@@ -9,7 +9,9 @@ bits(E) ∈ {0,1}^(tile × 33) (32 key bits ‖ ones column for counting),
 
 then `acc & 1` yields per-bin XOR folds (bit-parity == XOR) and the parity
 bitmap (count parity) in one shot.  The grid walks element tiles; `acc`
-lives in VMEM scratch for the whole pass.
+lives in VMEM scratch for the whole pass.  The batched kernel keeps keys on
+the lane axis and computes the same product as Hᵀ (n × tile) against bitsᵀ
+(33 × tile), contracted over lanes, with bf16 0/1 operands.
 
 Two binning reductions are provided (both keyed by murmur-finalizer mix32):
 
@@ -124,29 +126,41 @@ def bin_parity_xorsum(
 
 
 def _units_kernel(seeds_ref, elems_ref, valid_ref, o_ref, acc_ref, *, n_bins: int, nt: int):
-    """Grid (U, nt): per unit u, walk its element tiles accumulating Hᵀ @ bits."""
+    """Grid (U, nt): per unit u, walk its element tiles accumulating Hᵀ @ bits.
+
+    Elements ride the lane axis as a ``(1, tile)`` row, so the one-hot is
+    built transposed, ``(n, tile)``, and the bit planes as ``(33, tile)``;
+    the MXU contracts both over the lane axis.  0/1 operands are exact in
+    bf16 and every per-tile count (≤ tile ≤ 1024) is exact in the f32
+    accumulator, so the int32 running sum is bit-identical to integer math.
+    """
+    u = pl.program_id(0)
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    e = elems_ref[...][0].astype(jnp.uint32)   # (tile,)
-    valid = valid_ref[...][0] > 0
-    seed = seeds_ref[...][0]                   # this unit's per-round bin seed
+    e = elems_ref[...].astype(jnp.uint32)      # (1, tile)
+    valid = valid_ref[...] > 0                 # (1, tile)
+    seed = seeds_ref[u].astype(jnp.uint32)     # this unit's bin seed, from SMEM
     bins = mulshift_bins(mix32_jnp(e, seed), n_bins)
-    onehot = (
-        (bins[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, n_bins), 1))
-        & valid[:, None]
-    ).astype(jnp.int32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    bits = ((e[:, None] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-    bits = jnp.concatenate([bits, valid[:, None].astype(jnp.int32)], axis=1)  # ‖ ones
-    acc_ref[...] += jnp.dot(onehot.T, bits, preferred_element_type=jnp.int32)
+    hit = (jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0) == bins) & valid
+    onehot = jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)  # (n, tile)
+    planes = jax.lax.broadcasted_iota(jnp.int32, (33, 1), 0)
+    shifts = jnp.minimum(planes, 31).astype(jnp.uint32)
+    key_bits = ((e >> shifts) & jnp.uint32(1)).astype(jnp.int32)
+    # rows 0..31: key bit planes; row 32: the ones column (valid) for counting
+    bit = jnp.where(planes == 32, valid.astype(jnp.int32), key_bits)
+    bits = bit.astype(jnp.float32).astype(jnp.bfloat16)
+    counts = jax.lax.dot_general(
+        onehot, bits, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )                                          # (n, 33)
+    acc_ref[...] += counts.astype(jnp.int32)
 
     @pl.when(ti == nt - 1)
     def _emit():
-        o_ref[...] = (acc_ref[...] & 1)[None]
+        o_ref[...] = acc_ref[...] & 1
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "tile", "interpret"))
@@ -174,22 +188,25 @@ def bin_parity_xorsum_units(
         tile = max(128, min(1024, ceil_to(E, 128)))
     Ep = max(tile, ceil_to(E, tile))
     pad = Ep - E
-    e_p = jnp.pad(e, ((0, 0), (0, pad)))
-    v_p = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))
+    # (U, 1, Ep) rows: a (1, tile) block spans the full second-minor dim
+    e_p = jnp.pad(e, ((0, 0), (0, pad)))[:, None, :]
+    v_p = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))[:, None, :]
     nt = Ep // tile
     out = pl.pallas_call(
         functools.partial(_units_kernel, n_bins=n_bins, nt=nt),
-        grid=(U, nt),
-        in_specs=[
-            pl.BlockSpec((1,), lambda u, i: (u,)),
-            pl.BlockSpec((1, tile), lambda u, i: (u, i)),
-            pl.BlockSpec((1, tile), lambda u, i: (u, i)),
-        ],
-        out_specs=pl.BlockSpec((1, n_bins, 33), lambda u, i: (u, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(U, nt),
+            in_specs=[
+                pl.BlockSpec((None, 1, tile), lambda u, i, s: (u, 0, i)),
+                pl.BlockSpec((None, 1, tile), lambda u, i, s: (u, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((None, n_bins, 33), lambda u, i, s: (u, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((n_bins, 33), jnp.int32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((U, n_bins, 33), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((n_bins, 33), jnp.int32)],
         interpret=interpret,
-    )(seeds.astype(jnp.uint32), e_p, v_p)
+    )(jax.lax.bitcast_convert_type(seeds.astype(jnp.uint32), jnp.int32), e_p, v_p)
     return out[:, :, 32], out[:, :, :32]
 
 

@@ -1,6 +1,6 @@
 """GF(2) dense matmul Pallas kernel — the MXU workhorse for PBS coding.
 
-C = (A @ B) mod 2 with 0/1 int32 operands.  This single kernel implements
+C = (A @ B) mod 2 for 0/1 matrices.  This single kernel implements
 both BCH hot loops after the DESIGN.md §3 reformulation:
 
 * **syndromes**:  sketches = (parity_bitmaps @ syndrome_matrix) mod 2
@@ -8,12 +8,13 @@ both BCH hot loops after the DESIGN.md §3 reformulation:
 * **Chien search**: evals = (locator_bits @ chien_matrix) mod 2
   with A = (groups, (t+1)*m), B = ((t+1)*m, n*m).
 
-Integer accumulation is exact (counts ≤ K < 2^31), so a single `& 1` after
-the k loop gives the GF(2) product.  On a real TPU the operands are int8 with
-int32 MXU accumulation; interpret mode validates the same dataflow on CPU.
-Block shapes are hardware-aligned (lane dim multiples of 128, sublane of 8);
-the K (reduction) grid axis is innermost so each (i, j) output tile
-accumulates in a VMEM scratch across sequential k steps.
+The operands are padded as int8 and the MXU accumulates in int32 (it takes
+no int32 operands), which is exact for counts ≤ K < 2^31, so a single `& 1`
+after the k loop gives the GF(2) product; interpret mode runs the same
+dataflow on CPU.  Block shapes are hardware-aligned (lane dim multiples of
+128, row blocks multiples of the 32-row int8 tile); the K (reduction) grid
+axis is innermost so each (i, j) output tile accumulates in a VMEM scratch
+across sequential k steps.
 """
 from __future__ import annotations
 
@@ -54,18 +55,20 @@ def gf2_matmul(
     bk: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """(A @ B) mod 2 for 0/1 int32 matrices of any shape (padded internally)."""
+    """(A @ B) mod 2 for 0/1 matrices of any shape; int32 result.
+
+    Operands are padded to block multiples as int8 internally."""
     interpret = resolve_interpret(interpret)
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
     # clamp block sizes to (padded) problem dims, keeping HW alignment
-    bm_ = min(bm, _ceil_to(m, 8))
+    bm_ = min(_ceil_to(bm, 32), _ceil_to(m, 32))
     bn_ = min(bn, _ceil_to(n, 128))
     bk_ = min(bk, _ceil_to(k, 128))
     mp, np_, kp = _ceil_to(m, bm_), _ceil_to(n, bn_), _ceil_to(k, bk_)
-    a_p = jnp.zeros((mp, kp), jnp.int32).at[:m, :k].set(a.astype(jnp.int32))
-    b_p = jnp.zeros((kp, np_), jnp.int32).at[:k, :n].set(b.astype(jnp.int32))
+    a_p = jnp.zeros((mp, kp), jnp.int8).at[:m, :k].set(a.astype(jnp.int8))
+    b_p = jnp.zeros((kp, np_), jnp.int8).at[:k, :n].set(b.astype(jnp.int8))
     nk = kp // bk_
     out = pl.pallas_call(
         functools.partial(_kernel, nk=nk),

@@ -13,13 +13,14 @@ its traced Python body.  A jit body only executes when JAX traces a new
 compilations — the serving loops diff it across a run and publish the delta
 as ``stats["retraces"]``, turning "the shape buckets held" from a hope into
 an assertable number.  ``enable_persistent_cache`` additionally wires JAX's
-on-disk compilation cache so re-traced signatures at least skip XLA
+on-disk compilation cache (``JAX_COMPILATION_CACHE_DIR``, else a fixed
+directory in the checkout) so re-traced signatures at least skip XLA
 compilation across processes.
 """
 from __future__ import annotations
 
 import os
-import tempfile
+from pathlib import Path
 
 import jax
 
@@ -48,36 +49,31 @@ def retrace_counts() -> dict:
     return dict(_RETRACES["by_fn"])
 
 
+# Fixed, checkout-relative default for JAX's persistent compilation cache:
+# the directory is part of the cache key, so it must not move between runs.
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 _CACHE_DIR: str | None = None
 
 
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at an on-disk directory.
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Idempotent and best-effort: the first call wires the cache (default
-    location under the system temp dir, overridable via ``path`` or the
-    ``REPRO_JAX_CACHE_DIR`` env var; set the env var to ``off`` to disable),
-    later calls return the already-wired directory.  Backends that do not
-    support the cache simply ignore it — retrace *avoidance* comes from the
-    pow2 shape buckets, the cache only de-duplicates XLA compilation time
-    across processes.  Returns the cache dir, or None when disabled.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache and no other
+    directory is used.  Otherwise the cache lives at ``DEFAULT_CACHE_DIR``
+    (``<checkout>/.jax_cache``).  Call it before the process's first
+    compilation: JAX fixes the cache when it first compiles.  Idempotent:
+    later calls return the directory the first call chose.  Retrace
+    *avoidance* comes from the pow2 shape buckets; the cache only
+    de-duplicates XLA compilation across processes.
     """
     global _CACHE_DIR
     if _CACHE_DIR is not None:
         return _CACHE_DIR
-    if path is None:
-        path = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if path is not None and path.lower() in ("", "0", "off", "disable"):
-        return None
-    if path is None:
-        path = os.path.join(tempfile.gettempdir(), "repro-pbs-jax-cache")
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:           # unsupported backend/config: shape buckets
-        return None             # still bound compiles, so just carry on
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _CACHE_DIR = path
     return path
 

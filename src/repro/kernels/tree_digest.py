@@ -31,8 +31,8 @@ def _kernel(elems_ref, valid_ref, seeds_ref, o_ref, acc_ref, *, nt: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    e = elems_ref[...].reshape(-1).astype(jnp.uint32)  # (tile,)
-    valid = valid_ref[...].reshape(-1).astype(jnp.int32)  # (tile,)
+    e = elems_ref[...].astype(jnp.uint32)  # (tile,)
+    valid = valid_ref[...].astype(jnp.int32)  # (tile,)
     seeds = seeds_ref[...].astype(jnp.uint32)  # (ell,)
     h1 = mix32_jnp(e, 0x5EED)[:, None]  # (tile, 1)
     h = mix32_jnp(h1 ^ seeds[None, :], 0x7077)  # (tile, ell)
@@ -72,17 +72,19 @@ def tree_digest(
         e = jnp.pad(e, ((0, 0), (0, pad)))
         valid = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))
     nt = Ep // tile
+    # (R, 1, E) views: a squeezed (1, tile) block spans the full second-minor
+    # dim, which the TPU's (8, 128) block rule accepts; (1, ell) rows out
     out = pl.pallas_call(
         functools.partial(_kernel, nt=nt),
         grid=(R, nt),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda r, i: (r, i)),
-            pl.BlockSpec((1, tile), lambda r, i: (r, i)),
+            pl.BlockSpec((None, None, tile), lambda r, i: (r, 0, i)),
+            pl.BlockSpec((None, None, tile), lambda r, i: (r, 0, i)),
             pl.BlockSpec((ell,), lambda r, i: (0,)),
         ],
-        out_specs=pl.BlockSpec((1, ell), lambda r, i: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, ell), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, ell), lambda r, i: (r, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, 1, ell), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, ell), jnp.int32)],
         interpret=interpret,
-    )(e, valid.astype(jnp.int32), seeds.astype(jnp.uint32))
-    return out
+    )(e[:, None, :], valid.astype(jnp.int32)[:, None, :], seeds.astype(jnp.uint32))
+    return out[:, 0, :]

@@ -55,6 +55,7 @@ from repro.core.tow import (
     tow_sketches,
 )
 from repro.kernels.ops import bch_decode_batched
+from repro.kernels.platform import enable_persistent_cache
 from repro.obs import NULL_TRACER, Recorder
 from repro.recon.engine import encode_side, encode_side_ext
 from repro.recon.session import (
@@ -472,6 +473,7 @@ class _Endpoint:
         recorder: Recorder | None = None,
         tracer=None,
     ):
+        enable_persistent_cache()
         self._stream = FrameStream(transport, channel=channel)
         self._interpret = interpret
         # telemetry (DESIGN.md §14): wire_stats derives from the recorder's

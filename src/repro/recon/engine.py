@@ -19,9 +19,9 @@ Per call (DESIGN.md §5 round dataflow), for all U packed units at once:
 
 Shape polymorphism is confined to (U, Wa, Wb, R, X, F), all bucketed to
 powers of two by the planner, so a serving loop settles into a bounded set
-of compiled variants per cohort code.  On TPU the per-round overlay buffers
-are donated — they are dead after the call, so XLA may reuse their memory
-for outputs.
+of compiled variants per cohort code.  The per-round overlay buffers are
+not donated: they are a few KB, and most of them match no output shape, so
+XLA could not alias them anyway.
 
 ``encode_side`` is the single-side half of the same pass — one endpoint's
 row build + bin/sketch/checksum without the other side or the decode — used
@@ -347,36 +347,22 @@ def _encode_side_ext(
     return sketch_groups_range(parity, code, t0, interpret=interpret)
 
 
-# Per-round overlay buffers are dead after the call; donating them lets XLA
-# alias their device memory on TPU.  Off-TPU donation is unsupported and
-# only warns, so it stays off there.
-_ROUND_BUFFERS = (
-    "row_map", "unit_valid", "seeds", "removed", "removed_cnt",
-    "added", "added_cnt", "fseeds", "fbins", "fcnt",
-)
-
-
 @functools.lru_cache(maxsize=None)
-def _jitted_executor(donate: bool):
+def _jitted_executor():
     return jax.jit(
         _execute_round,
         static_argnames=("n", "t", "width_a", "width_b", "interpret"),
-        donate_argnames=_ROUND_BUFFERS if donate else (),
     )
 
 
 def execute_round(*args, **kwargs):
-    """Jitted ``_execute_round``; the backend probe for buffer donation is
-    deferred to call time so importing this module never initializes JAX."""
+    """Jitted ``_execute_round``."""
     with _DISPATCH_TRACER.annotate("repro.execute_round"):
-        return _jitted_executor(jax.default_backend() == "tpu")(*args, **kwargs)
+        return _jitted_executor()(*args, **kwargs)
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_side_executor():
-    # No donation here: a wire endpoint re-reads nothing either, but the
-    # overlay buffers are tiny and the call count is one per cohort-round —
-    # keep the single-side path free of backend probes.
     return jax.jit(_encode_side, static_argnames=("n", "t", "width", "interpret"))
 
 
@@ -386,11 +372,9 @@ def encode_side(*args, **kwargs):
         return _jitted_side_executor()(*args, **kwargs)
 
 
-# Extension executors stay donation-free: a cohort may extend several levels
-# over the same overlay arrays, and the host re-dispatches from the numpy
-# plan arrays each level anyway.  (n, t0, t1) are static — the deterministic
-# t-ladder keeps the signature set bounded, so a warm serving loop extends
-# with zero retraces (DESIGN.md §16).
+# Extension executors: (n, t0, t1) are static — the deterministic t-ladder
+# keeps the signature set bounded, so a warm serving loop extends with zero
+# retraces (DESIGN.md §16).
 
 
 @functools.lru_cache(maxsize=None)

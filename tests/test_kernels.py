@@ -8,7 +8,11 @@ import jax.numpy as jnp
 
 from repro.core.bch import BCHCode, batched_decode, sketch_from_positions
 from repro.kernels import ref
-from repro.kernels.bin_xorsum import bin_parity_xorsum, xor_bits_to_u32
+from repro.kernels.bin_xorsum import (
+    bin_parity_xorsum,
+    bin_parity_xorsum_units,
+    xor_bits_to_u32,
+)
 from repro.kernels.gf2_matmul import gf2_matmul
 from repro.kernels.ops import (
     bch_decode_batched,
@@ -19,6 +23,7 @@ from repro.kernels.ops import (
     tow_estimate,
 )
 from repro.kernels.tow_sketch import tow_sketch
+from repro.kernels.tree_digest import tree_digest
 
 
 @pytest.mark.parametrize(
@@ -70,6 +75,52 @@ def test_bin_xorsum_tile_invariance(tile):
     p_ref, xb_ref, _ = ref.bin_parity_xorsum_ref(elems, 127, 7)
     np.testing.assert_array_equal(np.array(p1), p_ref)
     np.testing.assert_array_equal(np.array(x1), xb_ref)
+
+
+def _ragged_rows(rng, rows: int, width: int):
+    """(rows, width) uint32 keys with a random 0/1 valid prefix per row
+    (some rows empty, some full) — the padded-row layout the engine feeds."""
+    elems = rng.integers(1, 1 << 32, size=(rows, width), dtype=np.uint64).astype(np.uint32)
+    counts = rng.integers(0, width + 1, size=rows)
+    counts[0], counts[-1] = 0, width
+    valid = (np.arange(width)[None, :] < counts[:, None]).astype(np.int32)
+    return elems, valid
+
+
+@pytest.mark.parametrize(
+    "units,width,n_bins",
+    [
+        (5, 37, 63),        # unit count not a multiple of 8; row < one tile
+        (13, 300, 255),     # odd units, row spans a padded second tile
+        (3, 1500, 1023),    # wide field (m = 10), two 1024-key tiles
+    ],
+)
+def test_bin_units_layouts(units, width, n_bins):
+    rng = np.random.default_rng(units * 7 + n_bins)
+    elems, valid = _ragged_rows(rng, units, width)
+    # seeds above 2^31 exercise the int32 bitcast through scalar memory
+    seeds = rng.integers(0, 1 << 32, size=units, dtype=np.uint64).astype(np.uint32)
+    seeds[0] = 0xFFFFFFFF
+    parity, xor_bits = bin_parity_xorsum_units(
+        jnp.array(elems), jnp.array(valid), jnp.array(seeds), n_bins=n_bins
+    )
+    p_ref, x_ref = ref.bin_parity_xorsum_units_ref(elems, valid, seeds, n_bins)
+    np.testing.assert_array_equal(np.array(parity), p_ref)
+    np.testing.assert_array_equal(np.array(xor_bits_to_u32(xor_bits)), x_ref)
+
+
+@pytest.mark.parametrize("rows,width", [(3, 40), (9, 700)])
+def test_tree_digest_layouts(rows, width):
+    """Rows shorter than one tile and rows spanning two tiles, against the
+    ToW oracle on each row's valid prefix."""
+    rng = np.random.default_rng(rows * 31 + width)
+    elems, valid = _ragged_rows(rng, rows, width)
+    seeds = rng.integers(0, 1 << 32, size=32, dtype=np.uint64).astype(np.uint32)
+    out = np.array(tree_digest(jnp.array(elems), jnp.array(valid), jnp.array(seeds), ell=32))
+    expect = np.stack([
+        ref.tow_sketch_ref(elems[r][valid[r] != 0], seeds) for r in range(rows)
+    ])
+    np.testing.assert_array_equal(out, expect)
 
 
 @pytest.mark.parametrize("ell", [32, 128])

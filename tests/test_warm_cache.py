@@ -8,10 +8,17 @@ jit recompilations.  ``stats["retraces"]`` counts actual traced executions
 of the jitted bodies, so these tests fail if anyone reintroduces an
 unbucketed shape into the hot path.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
+import pytest
 
 from repro.core.pbs import PBSConfig
 from repro.core.simdata import make_pair
+from repro.kernels import platform
 from repro.net import AliceEndpoint, HubEndpoint, InMemoryDuplex, run_hub, run_hub_epoch
 from repro.recon import ReconcileServer
 
@@ -91,3 +98,38 @@ def test_hub_epoch_soak_retraces_zero_after_warmup():
     # epoch 1 is warmup; from epoch 2 on, every kernel signature must
     # already be compiled — cross-round AND cross-epoch
     assert retraces[1:] == [0, 0], retraces
+
+
+_CACHE_PROBE = """
+import os, jax, jax.numpy as jnp
+from repro.kernels.platform import enable_persistent_cache
+path = enable_persistent_cache()
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+print(path)
+print(jax.config.jax_compilation_cache_dir)
+print(len(os.listdir(path)))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_persistent_cache_placement(tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` is the cache when set; otherwise the
+    fixed checkout directory is.  Run in a fresh interpreter: JAX fixes its
+    cache at a process's first compilation."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (str(Path(platform.__file__).parents[2]), env.get("PYTHONPATH")) if p
+    )
+    expect = str(tmp_path / "jax-cache") if from_env else platform.DEFAULT_CACHE_DIR
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = expect
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, check=True,
+        capture_output=True, text=True, timeout=120,
+    ).stdout.split()
+    assert out[0] == out[1] == expect
+    assert int(out[2]) >= 1          # the compiled program was written there
+    if not from_env:                 # a fixed, git-ignored checkout path
+        checkout = Path(platform.__file__).parents[3]
+        assert Path(expect) == checkout / ".jax_cache"
+        assert ".jax_cache/" in (checkout / ".gitignore").read_text().split()
