@@ -33,6 +33,8 @@ from dataclasses import dataclass, field, replace
 
 import numpy as np
 
+from repro.obs.trace import current_tracer
+
 from .bch import (
     BCHCode,
     batched_decode,
@@ -258,10 +260,14 @@ def group_view(elems: np.ndarray, g: int, seed_groups: int):
 
 
 def new_session_state(a: np.ndarray, b: np.ndarray, plan: ProtocolPlan) -> SessionState:
-    grp_b, order_b, bounds_b = group_view(b, plan.g, plan.seed_groups)
-    grp_a, order_a, bounds_a = group_view(a, plan.g, plan.seed_groups)
+    tracer = current_tracer()
+    with tracer.span("session.group_view", keys=len(a) + len(b)):
+        grp_b, order_b, bounds_b = group_view(b, plan.g, plan.seed_groups)
+        grp_a, order_a, bounds_a = group_view(a, plan.g, plan.seed_groups)
+    with tracer.span("session.member_set", keys=len(a)):
+        a_set = set(int(x) for x in a)
     return SessionState(
-        a=a, b=b, a_set=set(int(x) for x in a), diff=set(),
+        a=a, b=b, a_set=a_set, diff=set(),
         units=[Unit(uid=i, group=i) for i in range(plan.g)], next_uid=plan.g,
         group_b=grp_b, order_b=order_b, bounds_b=bounds_b,
         group_a=grp_a, order_a=order_a, bounds_a=bounds_a,

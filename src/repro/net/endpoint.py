@@ -29,7 +29,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import derive_seed
@@ -56,8 +55,8 @@ from repro.core.tow import (
 )
 from repro.kernels.ops import bch_decode_batched
 from repro.kernels.platform import enable_persistent_cache
-from repro.obs import NULL_TRACER, Recorder
-from repro.recon.engine import encode_side, encode_side_ext
+from repro.obs import Recorder, current_tracer
+from repro.recon.engine import encode_side, encode_side_ext, readback
 from repro.recon.session import (
     CohortRoundPlan,
     ReconSession,
@@ -107,6 +106,7 @@ def encode_round_rows(
     side: str,
     interpret: bool | None,
     launches: dict | None = None,
+    tracer=None,
 ) -> dict[int, _SessionRows]:
     """Dispatch every cohort's single-side executor, then collect per-session
     row slices (async dispatch overlaps cohorts).  Shared by the pair
@@ -134,7 +134,7 @@ def encode_round_rows(
         inflight.append((plan, out))
     per: dict[int, _SessionRows] = {}
     for plan, out in inflight:
-        sk, xors, csum = (np.asarray(x) for x in jax.device_get(out))
+        sk, xors, csum = (np.asarray(x) for x in readback(out, "encode", tracer))
         for sess, base, active, bin_seed in plan.members:
             rows = slice(base, base + len(active))
             per[sess.sid] = _SessionRows(
@@ -149,6 +149,7 @@ def encode_round_rows_ext(
     level: int,
     interpret: bool | None,
     launches: dict | None = None,
+    tracer=None,
 ) -> dict[int, tuple]:
     """Dispatch every cohort's *incremental* single-side executor for one
     rateless ladder level (DESIGN.md §16) and collect per-session slices.
@@ -183,7 +184,7 @@ def encode_round_rows_ext(
         inflight.append((plan, t0, t1, out))
     per: dict[int, tuple] = {}
     for plan, t0, t1, out in inflight:
-        inc = np.asarray(jax.device_get(out))
+        inc = np.asarray(readback(out, "encode_ext", tracer))
         for sess, base, active, _ in plan.members:
             per[sess.sid] = (inc[base : base + len(active)], t0, t1)
     return per
@@ -264,7 +265,7 @@ def serve_tree_frame(payload: bytes, walk: dict, stream, tally: dict,
         )
     tally["tree"] += _framed_len(payload)
     walk["bytes"] += _framed_len(payload)
-    with tracer.span("tree.level.dispatch", cat="device",
+    with tracer.span("tree.level.dispatch",
                      level=level, ranges=len(frontier)):
         cnt_b, cs_b, sk_b = level_digests(
             elems, frontier, tcfg, interpret=interpret
@@ -346,6 +347,7 @@ def decode_side_b_round(
     per: dict[int, _SessionRows],
     sk_a_of: dict,
     launches: dict | None = None,
+    tracer=None,
 ):
     """The serving side's round completion: place each session's
     frame-decoded sketches at its cohort rows, XOR with the resident side,
@@ -381,7 +383,9 @@ def decode_side_b_round(
     results: dict[int, tuple] = {}
     ctx: dict[int, tuple] = {}
     for plan, out in inflight:
-        ok_pad, pos_pad, cnt_pad = (np.asarray(x) for x in jax.device_get(out))
+        ok_pad, pos_pad, cnt_pad = (
+            np.asarray(x) for x in readback(out, "decode", tracer)
+        )
         # writable: the rateless ladder merges extension verdicts into the
         # per-session ok views in place (DESIGN.md §16)
         ok_pad = np.array(ok_pad)
@@ -477,10 +481,10 @@ class _Endpoint:
         self._stream = FrameStream(transport, channel=channel)
         self._interpret = interpret
         # telemetry (DESIGN.md §14): wire_stats derives from the recorder's
-        # wire.* rows; spans/instants go through the tracer (NULL_TRACER =
-        # disabled, free)
+        # wire.* rows; spans/instants go through the tracer (the one given,
+        # else the process-wide one; NULL_TRACER = disabled, free)
         self.recorder = recorder if recorder is not None else Recorder()
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else current_tracer()
         self._continuous = continuous
         self._degrade = degrade
         # phase-0 operating-regime guard (§15): planned d̂ beyond this
@@ -510,21 +514,26 @@ class _Endpoint:
     # -- submission ------------------------------------------------------
 
     def _submit(self, elems, cfg: PBSConfig | None, d_known: int | None):
-        cfg = cfg or PBSConfig()
-        elems = np.unique(np.asarray(elems, dtype=np.uint32))
-        sid = len(self._sessions)
-        self._d_known[sid] = d_known
-        if d_known is not None:
-            self._install(sid, elems, plan_from_d_known(cfg, d_known), append=True)
-        else:
-            self._sessions.append(None)
-            self._est_queue.append(sid)
-            self._pending_store(sid, elems, cfg)
-        return sid
+        with self.tracer.span("endpoint.submit", side=self.side,
+                              keys=len(elems)):
+            cfg = cfg or PBSConfig()
+            elems = np.unique(np.asarray(elems, dtype=np.uint32))
+            sid = len(self._sessions)
+            self._d_known[sid] = d_known
+            if d_known is not None:
+                self._install(sid, elems, plan_from_d_known(cfg, d_known),
+                              append=True)
+            else:
+                self._sessions.append(None)
+                self._est_queue.append(sid)
+                self._pending_store(sid, elems, cfg)
+            return sid
 
     def _install(self, sid, elems, plan, *, append: bool):
         a, b = (elems, _EMPTY) if self.side == "a" else (_EMPTY, elems)
-        sess = ReconSession(sid=sid, plan=plan, state=new_session_state(a, b, plan))
+        with self.tracer.span("session.state", side=self.side, keys=len(elems)):
+            state = new_session_state(a, b, plan)
+        sess = ReconSession(sid=sid, plan=plan, state=state)
         if append:
             self._sessions.append(sess)
         else:
@@ -625,7 +634,8 @@ class _Endpoint:
         raise NotImplementedError
 
     def _encode_round(self, plans: list[CohortRoundPlan]) -> dict[int, _SessionRows]:
-        return encode_round_rows(plans, self.side, self._interpret)
+        return encode_round_rows(plans, self.side, self._interpret,
+                                 tracer=self.tracer)
 
     @staticmethod
     def _schema(per: dict[int, _SessionRows], live: list[int]):
@@ -823,7 +833,7 @@ class AliceEndpoint(_Endpoint):
         leaves: list[TreeLeaf] = []
         level = 0
         while frontier:
-            with self.tracer.span("tree.level.dispatch", cat="device",
+            with self.tracer.span("tree.level.dispatch",
                                   level=level, ranges=len(frontier)):
                 cnt, cs, sk = level_digests(
                     elems, frontier, tcfg, interpret=self._interpret
@@ -866,8 +876,7 @@ class AliceEndpoint(_Endpoint):
             plans = batch.plan_round(rnd)
             if not plans:
                 break
-            with tracer.span("round.encode", cat="device", round=rnd,
-                             cohorts=len(plans)):
+            with tracer.span("round.encode", round=rnd, cohorts=len(plans)):
                 per = self._encode_round(plans)
             live = sorted(per)
             schema = self._schema(per, live)
@@ -1008,7 +1017,8 @@ class AliceEndpoint(_Endpoint):
                 if any(sess.sid in fail for sess, *_ in plan.members)
             ]
             inc_of = encode_round_rows_ext(
-                part_plans, self.side, level, self._interpret
+                part_plans, self.side, level, self._interpret,
+                tracer=self.tracer,
             )
             parts = [sid for sid in live if sid in fail and sid in inc_of]
             if not parts:
@@ -1317,7 +1327,7 @@ class BobEndpoint(_Endpoint):
         batch = self._ensure_batch()
         rnd = self._rnd + 1
         plans = batch.plan_round(rnd)
-        with self.tracer.span("round.encode", cat="device", round=rnd,
+        with self.tracer.span("round.encode", round=rnd,
                               cohorts=len(plans)):
             per = self._encode_round(plans)
         live = sorted(per)
@@ -1331,10 +1341,10 @@ class BobEndpoint(_Endpoint):
         # per cohort: place each session's frame sketches at its row slice,
         # XOR with our device-resident side, decode every unit at once
         # (padding rows carry zero sketches on both sides: trivially ok)
-        with self.tracer.span("round.decode", cat="device", round=rnd,
+        with self.tracer.span("round.decode", round=rnd,
                               sessions=len(live)):
             results, ctx = decode_side_b_round(
-                plans, per, dict(zip(live, blocks))
+                plans, per, dict(zip(live, blocks)), tracer=self.tracer
             )
         reply = wf.encode_round_reply(rnd, [results[sid] for sid in live], schema)
         self._stream.send(reply)
@@ -1379,7 +1389,8 @@ class BobEndpoint(_Endpoint):
             if any(sess.sid in fail for sess, *_ in plan.members)
         ]
         inc_of = encode_round_rows_ext(
-            part_plans, self.side, level, self._interpret
+            part_plans, self.side, level, self._interpret,
+            tracer=self.tracer,
         )
         parts = [sid for sid in c["live"] if sid in fail and sid in inc_of]
         if not parts:
@@ -1445,10 +1456,11 @@ class BobEndpoint(_Endpoint):
             if not hit:
                 continue
             ok_p, pos_p, cnt_p = (
-                np.asarray(x) for x in jax.device_get(
+                np.asarray(x) for x in readback(
                     bch_decode_batched(
                         jnp.asarray(buf, dtype=jnp.int32), n=n, t=t1
-                    )
+                    ),
+                    "parity_decode", self.tracer,
                 )
             )
             for sess, base, active, _ in plan.members:

@@ -48,7 +48,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.tow import ESTIMATE_LIMIT_FRAC, EstimateOutOfRange
@@ -63,6 +62,7 @@ from repro.core.pbs import (
     session_live,
 )
 from repro.kernels.ops import bch_decode_batched
+from repro.recon.engine import readback
 from repro.recon.session import (
     ReconSession,
     SessionBatch,
@@ -75,7 +75,7 @@ from repro.kernels.platform import (
     retrace_count,
     retrace_counts,
 )
-from repro.obs import NULL_TRACER, Recorder
+from repro.obs import Recorder, current_tracer
 from repro.wire import frames as wf
 from repro.wire.frames import ReplyUnit, WireError
 from repro.wire.varint import framed_len
@@ -217,9 +217,10 @@ class HubEndpoint:
         self._interpret = interpret
         # telemetry (DESIGN.md §14): the `stats` view derives from the
         # recorder's hub.* rows; every barrier/eviction/resume goes through
-        # the tracer (NULL_TRACER = disabled, free)
+        # the tracer (the one given, else the process-wide one;
+        # NULL_TRACER = disabled, free)
         self.recorder = recorder if recorder is not None else Recorder()
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else current_tracer()
         self._deadline = recv_deadline
         self.on_barrier = on_barrier
         self._continuous = continuous
@@ -278,16 +279,18 @@ class HubEndpoint:
         pairing with the peer's ``submit`` order, like the pair path);
         returns the peer-local sid.  Must precede the peer's admission."""
         peer = self._peers[channel]
-        elems = np.unique(np.asarray(set_b, dtype=np.uint32))
-        with self._lock:
-            if peer.admitted:
-                raise RuntimeError(
-                    f"channel {channel} already admitted; submit before serve "
-                    "or from the on_barrier hook for late joiners"
-                )
-            peer.pending.append((elems, cfg or PBSConfig(), d_known))
-            peer.d_known.append(d_known)
-            return len(peer.pending) - 1
+        with self.tracer.span("hub.submit", channel=channel,
+                              keys=len(set_b)):
+            elems = np.unique(np.asarray(set_b, dtype=np.uint32))
+            with self._lock:
+                if peer.admitted:
+                    raise RuntimeError(
+                        f"channel {channel} already admitted; submit before "
+                        "serve or from the on_barrier hook for late joiners"
+                    )
+                peer.pending.append((elems, cfg or PBSConfig(), d_known))
+                peer.d_known.append(d_known)
+                return len(peer.pending) - 1
 
     def submit_tree(
         self,
@@ -683,6 +686,12 @@ class HubEndpoint:
             self._joiners = [ch for ch in self._joiners if ch not in joiners]
         if not joiners:
             return False
+        with self.tracer.span("hub.admit", peers=len(joiners)) as span:
+            return self._admit_joiners(joiners, rnd, span)
+
+    def _admit_joiners(self, joiners: list[int], rnd: int, span) -> bool:
+        """``_admit``'s work once it has joiners; ``span`` is its
+        ``hub.admit`` span, which learns the sessions admitted."""
         # tree phase (§15): drive every tree-staged joiner's whole walk —
         # one digest->verdict barrier per level, same deadline semantics —
         # before phase 0; its leaf sessions join the pending queue as
@@ -750,21 +759,23 @@ class HubEndpoint:
             {ch: _phase0_handler(ch) for ch in est_idx}, phase="admission"
         )
 
+        admitted = 0
         for ch in joiners:
             peer = self._peers[ch]
             if peer.retired:
                 continue
-            new = [
-                ReconSession(
-                    sid=len(self._sessions) + i,
-                    plan=plan,
-                    state=new_session_state(_EMPTY, set_b, plan),
+            new = []
+            for i, (plan, (set_b, _, _)) in enumerate(
+                zip(plans[ch], pending_of[ch])
+            ):
+                with self.tracer.span("session.state", side=self.side,
+                                      keys=len(set_b)):
+                    state = new_session_state(_EMPTY, set_b, plan)
+                new.append(ReconSession(
+                    sid=len(self._sessions) + i, plan=plan, state=state,
                     rnd0=rnd,
-                )
-                for i, (plan, (set_b, _, _)) in enumerate(
-                    zip(plans[ch], pending_of[ch])
-                )
-            ]
+                ))
+            admitted += len(new)
             with self._lock:
                 # a submit that raced in after the snapshot stays pending
                 # and admits at the next barrier (its own rnd0)
@@ -786,6 +797,7 @@ class HubEndpoint:
                 peer.marks = {k: peer.tally[k] for k in peer.marks}
             peer.sessions.extend(new)
             self._batch.add_sessions(new)   # appends to self._sessions
+        span.set(sessions=admitted)
         return True
 
     # -- continuous sync (DESIGN.md §11) ----------------------------------
@@ -943,6 +955,10 @@ class HubEndpoint:
 
     def serve(self) -> dict[int, PeerOutcome]:
         """Drive every peer's sessions to completion; channel -> outcome."""
+        with self.tracer.span("hub.serve", epoch=self._epoch):
+            return self._serve()
+
+    def _serve(self) -> dict[int, PeerOutcome]:
         st = self._stats = {
             "epoch": self._epoch,
             "rounds": 0, "cohort_rounds": 0,
@@ -962,7 +978,6 @@ class HubEndpoint:
         rnd = self._rnd = 0
         hook_fired_at = -1
         tracer = self.tracer
-        tracer.instant("hub.serve", epoch=self._epoch)
         if self._epoch_open:
             with tracer.span("hub.epoch_handshake", epoch=self._epoch):
                 self._epoch_handshake()
@@ -1018,15 +1033,16 @@ class HubEndpoint:
             # shared plan over every surviving live session (evictions
             # above already marked their sessions failed), then the fused
             # single-side encode: 2 kernel launches per cohort, all peers
-            plans = self._batch.plan_round(rnd)
+            with tracer.span("hub.plan_round", round=rnd) as span:
+                plans = self._batch.plan_round(rnd)
+                span.set(cohorts=len(plans))
             # launch counters are bumped at the dispatch sites inside the
             # helpers, so the fusion stats measure dispatches — one encode
             # and one decode per cohort regardless of peer count — rather
             # than echoing the planner's own bookkeeping
-            with tracer.span("hub.encode", cat="device", round=rnd,
-                             cohorts=len(plans)):
+            with tracer.span("hub.encode", round=rnd, cohorts=len(plans)):
                 per = encode_round_rows(plans, self.side, self._interpret,
-                                        launches=st)
+                                        launches=st, tracer=tracer)
             if plans:
                 st["rounds"] = rnd
             st["cohort_rounds"] += len(plans)
@@ -1173,10 +1189,10 @@ class HubEndpoint:
 
         # one decode launch per cohort, all peers' units stacked; sessions
         # of peers evicted after planning keep zero rows and are skipped
-        with self.tracer.span("hub.decode", cat="device", round=rnd,
-                              cohorts=len(plans)):
+        with self.tracer.span("hub.decode", round=rnd, cohorts=len(plans)):
             results, ctx = decode_side_b_round(plans, per, sk_a_of,
-                                               launches=self._stats)
+                                               launches=self._stats,
+                                               tracer=self.tracer)
 
         round_ctx: dict[int, tuple] = {}
         for ch, live_g in peer_live.items():
@@ -1242,12 +1258,11 @@ class HubEndpoint:
                 plan for plan in plans
                 if any(sess.sid in failing for sess, *_ in plan.members)
             ]
-            with self.tracer.span("hub.parity_encode", cat="device",
-                                  round=rnd, level=level,
-                                  cohorts=len(part_plans)):
+            with self.tracer.span("hub.parity_encode", round=rnd,
+                                  level=level, cohorts=len(part_plans)):
                 inc_of = encode_round_rows_ext(
                     part_plans, self.side, level, self._interpret,
-                    launches=st,
+                    launches=st, tracer=self.tracer,
                 )
             # mirror of each peer's own participation check: failing
             # sessions whose cohort t still grows at this level
@@ -1333,8 +1348,8 @@ class HubEndpoint:
             }
             ch_of = {sid: ch for ch, parts in need.items() for sid in parts}
             entries: dict[int, tuple] = {}
-            with self.tracer.span("hub.parity_decode", cat="device",
-                                  round=rnd, level=level):
+            with self.tracer.span("hub.parity_decode", round=rnd,
+                                  level=level):
                 for plan in part_plans:
                     n, t = plan.store.n, plan.store.t
                     t1 = parity_extension_t(t, level, n)
@@ -1353,10 +1368,11 @@ class HubEndpoint:
                     if not hit:
                         continue
                     ok_p, pos_p, cnt_p = (
-                        np.asarray(x) for x in jax.device_get(
+                        np.asarray(x) for x in readback(
                             bch_decode_batched(
                                 jnp.asarray(buf, dtype=jnp.int32), n=n, t=t1
-                            )
+                            ),
+                            "parity_decode", self.tracer,
                         )
                     )
                     st["decode_launches"] = st.get("decode_launches", 0) + 1
@@ -1491,8 +1507,9 @@ def _drive_hub(
     for th in threads:
         th.start()
     outcomes = hub.serve()
-    for th in threads:
-        th.join(timeout=join_timeout)
+    with hub.tracer.span("hub.join_peers", peers=len(threads)):
+        for th in threads:
+            th.join(timeout=join_timeout)
     return outcomes, results, errors
 
 

@@ -29,7 +29,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.core.tow import EstimateOutOfRange
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import current_tracer
 from repro.wire.frames import WireError
 
 from .transport import Transport, TransportError, TransportTimeout
@@ -122,7 +122,7 @@ class ChaosTransport(Transport):
         # injected faults mark instants on the shared timeline so a chaos
         # soak's trace shows each drop/crash next to the ARQ recovery it
         # provoked; per-datagram, so guarded by ``enabled`` (DESIGN.md §14)
-        self._tracer = tracer if tracer is not None else NULL_TRACER
+        self._tracer = tracer if tracer is not None else current_tracer()
         self._rng = np.random.default_rng(plan.seed)
         self._held: bytes | None = None    # reorder: datagram awaiting swap
         self.crashed = False

@@ -27,7 +27,7 @@ from collections import deque
 
 import numpy as np
 
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import current_tracer
 from repro.wire import frames as wire_frames
 from repro.wire.frames import WireError, split_frame
 from repro.wire.varint import decode_uvarint, encode_uvarint, framed_len
@@ -291,7 +291,7 @@ class ReliableTransport(Transport):
         # per-datagram tracing is hot-path: every site below checks
         # ``_tracer.enabled`` first so the disabled default costs one
         # attribute read per send/recv (DESIGN.md §14)
-        self._tracer = tracer if tracer is not None else NULL_TRACER
+        self._tracer = tracer if tracer is not None else current_tracer()
         self._timeout = float(timeout)
         self._max_retries = int(max_retries)
         self._rto_max = max(float(rto_max), float(timeout))

@@ -3,10 +3,19 @@
 One typed metrics registry (``Recorder`` + ``SCHEMA``, DESIGN.md §14)
 absorbing every layer's ad-hoc stats ledger behind derived snapshots, and
 one zero-dep span tracer (``Tracer``/``NULL_TRACER``) exporting JSONL and
-Chrome-trace timelines of the whole serving stack.
+Chrome-trace timelines of the whole serving stack, installed once per
+process with ``set_tracer``/``use_tracer``.
 """
 from repro.obs.metrics import SCHEMA, MetricSpec, MetricsError, Recorder
-from repro.obs.trace import NULL_TRACER, NullTracer, Tracer, load_events
+from repro.obs.trace import (
+    NULL_TRACER,
+    NullTracer,
+    Tracer,
+    current_tracer,
+    load_events,
+    set_tracer,
+    use_tracer,
+)
 
 __all__ = [
     "SCHEMA",
@@ -17,4 +26,7 @@ __all__ = [
     "NullTracer",
     "NULL_TRACER",
     "load_events",
+    "set_tracer",
+    "current_tracer",
+    "use_tracer",
 ]

@@ -17,14 +17,22 @@ Two exports of the same event list:
   (https://ui.perfetto.dev), with thread-name metadata so each endpoint /
   hub / peer thread renders as its own labeled track.
 
-Tracing is **disabled by default and off the hot path**: every traced call
-site holds a ``Tracer`` reference that defaults to the module-level
-``NULL_TRACER`` singleton, whose ``span`` returns one shared no-op context
-manager and whose ``instant``/``counter`` are pass statements — no event
-list, no lock, no clock read.  Hot loops additionally guard per-datagram
-instrumentation behind ``tracer.enabled`` so the disabled path costs a
-single attribute read (the warm S=1024 bench gate runs with tracing
-disabled and is asserted unchanged).
+Tracing is **disabled by default and off the hot path**.  One tracer is
+installed per process: ``set_tracer(tracer)`` installs it (``None``
+removes it), ``current_tracer()`` reads it, and ``use_tracer(tracer)``
+installs it for a ``with`` block.  Every component that takes ``tracer=``
+(the hub, the pair endpoints, ``ReconcileServer``, ``SessionBatch``, the
+transports) resolves ``tracer if tracer is not None else
+current_tracer()`` once, when it is built; code with no component to hold
+a tracer (``core.pbs.new_session_state``, the round executors'
+``annotate`` sites) reads ``current_tracer()`` at the call.  With nothing
+installed that is the module-level ``NULL_TRACER`` singleton, whose
+``span`` returns one shared no-op context manager and whose
+``instant``/``counter`` are pass statements: no event list, no lock, no
+clock read.  Hot loops additionally guard per-datagram instrumentation
+behind ``tracer.enabled`` so the disabled path costs a single attribute
+read (the warm S=1024 bench gate runs with tracing disabled and is
+asserted unchanged).
 
 ``Tracer(jax_profiler=True)`` opt-in: ``annotate(name)`` then returns a
 ``jax.profiler.TraceAnnotation`` so kernel dispatch windows show up inside
@@ -36,6 +44,7 @@ from __future__ import annotations
 import json
 import threading
 import time
+from contextlib import contextmanager
 
 
 class _NullSpan:
@@ -48,6 +57,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args):
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -96,6 +108,10 @@ class _Span:
         self._tracer._emit(ev)
         return False
 
+    def set(self, **args):
+        """Add args known only once the span's work has run."""
+        self._ev["args"].update(args)
+
 
 class Tracer:
     """Collects trace events; timestamps are µs from tracer creation.
@@ -135,8 +151,9 @@ class Tracer:
     def span(self, name: str, cat: str = "host", **args) -> _Span:
         """A timed region: ``with tracer.span("cohort.collect", rnd=3):``.
 
-        ``cat`` buckets spans for occupancy accounting — ``device`` marks
-        time blocked on device readback, everything else is host time.
+        ``cat`` buckets spans for occupancy accounting: ``device`` is
+        used by ``device.readback`` alone and marks host time blocked on
+        the device; ``wire`` marks barrier waits; the rest is host work.
         ``args`` carry attribution (peer/channel/sid/round/cohort).
         """
         return _Span(self, {"name": name, "cat": cat, "ph": "X", "pid": 1,
@@ -194,6 +211,34 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return len(evs)
+
+
+_CURRENT = NULL_TRACER
+
+
+def set_tracer(tracer) -> None:
+    """Install ``tracer`` as the process-wide tracer; ``None`` removes it.
+
+    Components pick it up when they are built, so install it before
+    building the hub, endpoints or server whose spans it should record."""
+    global _CURRENT
+    _CURRENT = tracer if tracer is not None else NULL_TRACER
+
+
+def current_tracer():
+    """The process-wide tracer, or ``NULL_TRACER`` when none is installed."""
+    return _CURRENT
+
+
+@contextmanager
+def use_tracer(tracer):
+    """Install ``tracer`` for a ``with`` block, restoring the previous one."""
+    prev = _CURRENT
+    set_tracer(tracer)
+    try:
+        yield tracer
+    finally:
+        set_tracer(prev)
 
 
 def load_events(path) -> list[dict]:

@@ -45,20 +45,23 @@ from repro.kernels.bin_xorsum import (
 )
 from repro.kernels.ops import bch_decode_batched, sketch_groups, sketch_groups_range
 from repro.kernels.platform import count_retrace
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import current_tracer, set_tracer
 
-# Opt-in profiler hook (DESIGN.md §14): install a Tracer built with
-# jax_profiler=True and every executor dispatch window is annotated inside
-# a ``jax.profiler.trace`` capture.  The default NULL_TRACER hands back a
-# shared no-op context, so the un-opted path costs one with-statement.
-_DISPATCH_TRACER = NULL_TRACER
+# Opt-in profiler hook (DESIGN.md §14): with a Tracer built with
+# jax_profiler=True installed process-wide, every executor dispatch window
+# is annotated inside a ``jax.profiler.trace`` capture.  With none
+# installed NULL_TRACER hands back a shared no-op context, so the
+# un-opted path costs one with-statement.
+set_dispatch_tracer = set_tracer
 
 
-def set_dispatch_tracer(tracer) -> None:
-    """Install (or, with None, remove) the tracer whose ``annotate`` wraps
-    every ``execute_round``/``encode_side`` dispatch."""
-    global _DISPATCH_TRACER
-    _DISPATCH_TRACER = tracer if tracer is not None else NULL_TRACER
+def readback(out, what: str, tracer=None):
+    """Wait for the device outputs ``out`` and copy them to the host, under
+    the ``device.readback`` span: the one ``cat="device"`` span, so a trace
+    tells host time blocked on the chip from the host work around it."""
+    tracer = tracer if tracer is not None else current_tracer()
+    with tracer.span("device.readback", cat="device", what=what):
+        return jax.device_get(out)
 
 
 def _count_trace(name: str, probe) -> None:
@@ -357,7 +360,7 @@ def _jitted_executor():
 
 def execute_round(*args, **kwargs):
     """Jitted ``_execute_round``."""
-    with _DISPATCH_TRACER.annotate("repro.execute_round"):
+    with current_tracer().annotate("repro.execute_round"):
         return _jitted_executor()(*args, **kwargs)
 
 
@@ -368,7 +371,7 @@ def _jitted_side_executor():
 
 def encode_side(*args, **kwargs):
     """Jitted ``_encode_side`` (the per-endpoint half of ``execute_round``)."""
-    with _DISPATCH_TRACER.annotate("repro.encode_side"):
+    with current_tracer().annotate("repro.encode_side"):
         return _jitted_side_executor()(*args, **kwargs)
 
 
@@ -387,7 +390,7 @@ def _jitted_ext_executor():
 
 def execute_round_ext(*args, **kwargs):
     """Jitted ``_execute_round_ext`` (both sides' incremental syndrome XOR)."""
-    with _DISPATCH_TRACER.annotate("repro.execute_round_ext"):
+    with current_tracer().annotate("repro.execute_round_ext"):
         return _jitted_ext_executor()(*args, **kwargs)
 
 
@@ -400,5 +403,5 @@ def _jitted_side_ext_executor():
 
 def encode_side_ext(*args, **kwargs):
     """Jitted ``_encode_side_ext`` (one endpoint's ``MSG_PARITY`` payload)."""
-    with _DISPATCH_TRACER.annotate("repro.encode_side_ext"):
+    with current_tracer().annotate("repro.encode_side_ext"):
         return _jitted_side_ext_executor()(*args, **kwargs)

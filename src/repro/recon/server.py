@@ -62,11 +62,11 @@ from repro.kernels.platform import (
     retrace_counts,
 )
 from repro.kernels.tow_sketch import tow_sketch
-from repro.obs import NULL_TRACER, Recorder
+from repro.obs import Recorder, current_tracer
 
 from repro.kernels.ops import bch_decode_batched
 
-from .engine import execute_round, execute_round_ext
+from .engine import execute_round, execute_round_ext, readback
 from .session import (
     CohortRoundPlan,
     ReconSession,
@@ -185,9 +185,10 @@ class ReconcileServer:
         self._epoch = 0
         # telemetry (DESIGN.md §14): all run ledgers publish into the
         # recorder (the `stats` view derives from it) and every phase
-        # boundary is spanned through the tracer (NULL_TRACER = disabled).
+        # boundary is spanned through the tracer (the one given, else the
+        # process-wide one; NULL_TRACER = disabled).
         self.recorder = recorder if recorder is not None else Recorder()
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else current_tracer()
 
     def submit(
         self,
@@ -319,9 +320,9 @@ class ReconcileServer:
             while inflight:
                 key, rnd, plan, fut = inflight.popleft()
                 t0 = time.perf_counter()
-                with tracer.span("cohort.collect", cat="device",
-                                 n=key[0], t=key[1], round=rnd):
-                    out = jax.device_get(fut)
+                with tracer.span("cohort.collect", n=key[0], t=key[1],
+                                 round=rnd):
+                    out = readback(fut, "collect", tracer)
                 st["device_s"] += time.perf_counter() - t0
                 with tracer.span("cohort.apply", n=key[0], t=key[1], round=rnd,
                                  units=len(plan.arrays["row_map"])):
@@ -641,12 +642,16 @@ class ReconcileServer:
                 interpret=self._interpret,
             )
             ext["kernel_launches"] += 2  # bin rebuild + incremental matmul
-            acc = np.concatenate([acc, np.asarray(jax.device_get(inc))], axis=1)
+            acc = np.concatenate(
+                [acc, np.asarray(readback(inc, "encode_ext", self.tracer))],
+                axis=1,
+            )
             # only failing rateless rows carry content: settled/foreign rows
             # decode trivially as zero sketches and are never touched
             masked = np.where(fail[:, None], acc, 0)
-            ok_e, pos_e, _ = jax.device_get(
-                bch_decode_batched(jnp.asarray(masked), n=n, t=t_e)
+            ok_e, pos_e, _ = readback(
+                bch_decode_batched(jnp.asarray(masked), n=n, t=t_e),
+                "parity_decode", self.tracer,
             )
             ok_e, pos_e = np.asarray(ok_e), np.asarray(pos_e)
             dt = t_e - t_prev

@@ -64,7 +64,7 @@ from repro.core.pbs import (
 )
 from repro.kernels.platform import ceil_to as _ceil_to
 from repro.kernels.platform import pow2_bucket
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import current_tracer
 
 
 class StoreCapacityError(RuntimeError):
@@ -411,8 +411,9 @@ class SessionBatch:
         self.store_patches = 0         # apply_mutations calls that patched
         self.store_compactions = 0     # capacity overflows -> forced rebuilds
         # store-lifecycle timeline (DESIGN.md §14): builds span, compactions
-        # mark instants; NULL_TRACER (the default) makes both free
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # mark instants; with no tracer given or installed, NULL_TRACER
+        # makes both free
+        self.tracer = tracer if tracer is not None else current_tracer()
 
     # ---- upload-once element store -------------------------------------
 
@@ -535,36 +536,43 @@ class SessionBatch:
         row_of: dict = {}
         row_base: dict = {}
         nrows = 0
-        for s in members:
-            st, plan = s.state, s.plan
-            row_base[s.sid] = nrows
-            row_of.update(((s.sid, grp), nrows + grp) for grp in range(plan.g))
-            nrows += plan.g
+        layouts: dict[str, tuple] = {}
+        with self.tracer.span("store.layout", members=len(members)):
+            for s in members:
+                st, plan = s.state, s.plan
+                row_base[s.sid] = nrows
+                row_of.update(((s.sid, grp), nrows + grp)
+                              for grp in range(plan.g))
+                nrows += plan.g
+                for side in self.sides:
+                    elems, order, bounds = (
+                        (st.a, st.order_a, st.bounds_a) if side == "a"
+                        else (st.b, st.order_b, st.bounds_b)
+                    )
+                    vals[side].append(elems[order].astype(np.uint32))
+                    cnts[side].append(np.diff(bounds))
             for side in self.sides:
-                elems, order, bounds = (
-                    (st.a, st.order_a, st.bounds_a) if side == "a"
-                    else (st.b, st.order_b, st.bounds_b)
+                layouts[side] = _csr_layout(
+                    np.concatenate(vals[side]) if vals[side]
+                    else np.zeros(0, dtype=np.uint32),
+                    np.concatenate(cnts[side]) if cnts[side]
+                    else np.zeros(0, dtype=np.int64),
+                    self.COL_ALIGN, slack=self.mutable,
                 )
-                vals[side].append(elems[order].astype(np.uint32))
-                cnts[side].append(np.diff(bounds))
 
         sides: dict[str, SideStore] = {}
-        for side in self.sides:
-            flat, start, cnt, cap = _csr_layout(
-                np.concatenate(vals[side]) if vals[side]
-                else np.zeros(0, dtype=np.uint32),
-                np.concatenate(cnts[side]) if cnts[side]
-                else np.zeros(0, dtype=np.int64),
-                self.COL_ALIGN, slack=self.mutable,
-            )
-            sides[side] = SideStore(
-                flat=jnp.asarray(flat), start=jnp.asarray(start),
-                cnt=jnp.asarray(cnt), cnt_host=cnt,
-                h2d_bytes=flat.nbytes + start.nbytes + cnt.nbytes,
-                start_host=start,
-                flat_host=flat if self.mutable else None,
-                cap_host=cap if self.mutable else None,
-            )
+        nbytes = sum(f.nbytes + s.nbytes + c.nbytes
+                     for f, s, c, _ in layouts.values())
+        with self.tracer.span("store.upload", bytes=nbytes):
+            for side, (flat, start, cnt, cap) in layouts.items():
+                sides[side] = SideStore(
+                    flat=jnp.asarray(flat), start=jnp.asarray(start),
+                    cnt=jnp.asarray(cnt), cnt_host=cnt,
+                    h2d_bytes=flat.nbytes + start.nbytes + cnt.nbytes,
+                    start_host=start,
+                    flat_host=flat if self.mutable else None,
+                    cap_host=cap if self.mutable else None,
+                )
         store = CohortStore(
             n=n, t=t, m=bch_code(n, t).m,
             row_of=row_of, sides=sides, row_base=row_base,

@@ -39,7 +39,7 @@ from repro.core.pbs import KEY_BITS, PBSConfig
 from repro.core.tow import GAMMA, planned_d, tow_seeds, tow_sketches
 from repro.kernels.platform import pow2_bucket, retrace_count
 from repro.kernels.tree_digest import tree_digest
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import current_tracer
 from repro.wire import frames as wf
 
 SPAN = 1 << KEY_BITS
@@ -245,7 +245,7 @@ def partition_pair(
     wire flow would ship for the same pair.
     """
     tcfg = tree or TreeConfig()
-    tracer = tracer if tracer is not None else NULL_TRACER
+    tracer = tracer if tracer is not None else current_tracer()
     a = np.unique(np.asarray(set_a, dtype=np.uint32))
     b = np.unique(np.asarray(set_b, dtype=np.uint32))
     stats = TreeStats()

@@ -26,6 +26,7 @@ from repro.net import (
     run_hub,
     tcp_loopback_pair,
 )
+from repro.obs import Tracer, use_tracer
 
 
 class _SilentAfterPhase0(AliceEndpoint):
@@ -287,3 +288,87 @@ def test_unmultiplexed_frame_on_channel_stream_rejected():
     ta.send(wf.encode_mux(1, wf.encode_dhat(7)))
     msg_type, payload = stream.recv(timeout=1.0)
     assert msg_type == wf.MSG_DHAT and wf.decode_dhat(payload) == 7
+
+
+def _nested(child: dict, parent: dict) -> bool:
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_hub_trace_spans_cover_and_nest():
+    """Under a process-wide tracer, a 3-peer rejoin records the hub's
+    set-up, admission, planning and readback spans: on the hub's thread
+    they nest properly, ``hub.serve`` and ``hub.join_peers`` cover
+    everything after the submits, and ``device.readback`` is the only
+    ``cat="device"`` span."""
+    pairs = [make_pair(700, 12, np.random.default_rng(40 + i)) for i in range(3)]
+    tr = Tracer()
+    with use_tracer(tr):
+        hub = HubEndpoint(recv_deadline=30.0)
+        alices = {}
+        for i, (a, b) in enumerate(pairs):
+            cfg = PBSConfig(seed=70 + i)
+            ta, tb = InMemoryDuplex.pair()
+            ch = hub.add_peer(tb, label=f"p{i}")
+            hub.submit(ch, b, cfg=cfg, d_known=12)
+            ep = AliceEndpoint(ta, channel=ch)
+            ep.submit(a, cfg=cfg, d_known=12)
+            alices[ch] = ep
+        outcomes, results, errors = run_hub(hub, alices)
+    assert not errors and all(o.ok for o in outcomes.values())
+    for ch, (a, b) in zip(alices, pairs):
+        assert results[ch][0].diff == true_diff(a, b)
+
+    main = threading.get_ident()
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    mine = [e for e in spans if e["tid"] == main]
+    by = {}
+    for e in mine:
+        by.setdefault(e["name"], []).append(e)
+    for name in ("endpoint.submit", "hub.submit", "hub.serve", "hub.admit",
+                 "session.state", "session.group_view", "session.member_set",
+                 "hub.plan_round", "store.build", "store.layout",
+                 "store.upload", "device.readback", "hub.join_peers"):
+        assert name in by, name
+    assert len(by["endpoint.submit"]) == len(by["hub.submit"]) == 3
+    assert by["hub.admit"][0]["args"] == {"peers": 3, "sessions": 3}
+
+    # the hub thread's spans form a tree: any two are disjoint or nested
+    for i, x in enumerate(mine):
+        for y in mine[i + 1:]:
+            disjoint = (x["ts"] + x["dur"] <= y["ts"]
+                        or y["ts"] + y["dur"] <= x["ts"])
+            assert disjoint or _nested(x, y) or _nested(y, x), (x, y)
+
+    def inside(child: str, parent: str) -> None:
+        for c in by[child]:
+            assert any(_nested(c, p) for p in by[parent]), (child, parent)
+
+    inside("store.layout", "store.build")
+    inside("store.upload", "store.build")
+    inside("store.build", "hub.plan_round")
+    inside("hub.plan_round", "hub.serve")
+    inside("hub.admit", "hub.serve")
+    inside("device.readback", "hub.serve")
+    inside("session.group_view", "session.state")
+    inside("session.member_set", "session.state")
+    # each session state is built inside a peer's submit or the admission
+    for s in by["session.state"]:
+        assert any(_nested(s, p) for p in by["endpoint.submit"] + by["hub.admit"])
+    assert {s["args"]["side"] for s in by["session.state"]} == {"a", "b"}
+    assert all(r["args"]["what"] in ("encode", "decode")
+               for r in by["device.readback"])
+
+    # after the last submit, serve and the joins cover the run but for
+    # starting the peer threads (a few ms while they take the GIL)
+    last_submit = max(e["ts"] + e["dur"] for e in by["endpoint.submit"])
+    serve, join = by["hub.serve"][0], by["hub.join_peers"][0]
+    end = join["ts"] + join["dur"]
+    uncovered = (serve["ts"] - last_submit) + (join["ts"] - serve["ts"] - serve["dur"])
+    assert serve["ts"] >= last_submit and join["ts"] >= serve["ts"] + serve["dur"]
+    assert uncovered <= max(0.1 * (end - last_submit), 50e3)    # µs
+
+    # device wait is named by the readback span alone, on every thread
+    assert {e["name"] for e in spans if e["cat"] == "device"} == {"device.readback"}
+    peer_reads = [e for e in spans if e["tid"] != main and e["name"] == "device.readback"]
+    assert peer_reads           # the peers' own encodes, on their threads

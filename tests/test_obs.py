@@ -36,6 +36,7 @@ from repro.core.pbs import PBSConfig, reconcile
 from repro.core.simdata import make_pair
 from repro.net import (
     AliceEndpoint,
+    BobEndpoint,
     ChaosTransport,
     FaultPlan,
     HubEndpoint,
@@ -50,9 +51,13 @@ from repro.obs import (
     MetricsError,
     Recorder,
     Tracer,
+    current_tracer,
     load_events,
+    set_tracer,
+    use_tracer,
 )
-from repro.recon import ReconcileServer
+from repro.recon import ReconcileServer, engine
+from repro.recon.session import SessionBatch
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "tools"))
@@ -374,6 +379,66 @@ def test_tracer_span_structure():
     assert all(e["pid"] == 1 for e in evs)
 
 
+def test_set_tracer_and_use_tracer_install_and_remove():
+    assert current_tracer() is NULL_TRACER
+    tr, inner = Tracer(), Tracer()
+    set_tracer(tr)
+    try:
+        assert current_tracer() is tr
+        with use_tracer(inner) as got:
+            assert got is inner and current_tracer() is inner
+        assert current_tracer() is tr        # the previous one comes back
+    finally:
+        set_tracer(None)
+    assert current_tracer() is NULL_TRACER
+    with pytest.raises(RuntimeError):
+        with use_tracer(tr):
+            raise RuntimeError("boom")
+    assert current_tracer() is NULL_TRACER   # restored on an exception too
+
+
+def _components():
+    """(name, tracer) of one of each component built without ``tracer=``."""
+    raw_a, raw_b = InMemoryDuplex.pair()
+    return [
+        ("hub", HubEndpoint().tracer),
+        ("hub batch", HubEndpoint()._batch.tracer),
+        ("alice", AliceEndpoint(raw_a).tracer),
+        ("bob", BobEndpoint(raw_b).tracer),
+        ("server", ReconcileServer().tracer),
+        ("batch", SessionBatch([]).tracer),
+        ("arq", ReliableTransport(raw_a)._tracer),
+        ("chaos", ChaosTransport(raw_a, FaultPlan())._tracer),
+    ]
+
+
+def test_components_pick_up_the_installed_tracer():
+    for name, tracer in _components():
+        assert tracer is NULL_TRACER, name
+    tr = Tracer()
+    with use_tracer(tr):
+        for name, tracer in _components():
+            assert tracer is tr, name
+        # an explicit tracer= still wins over the installed one
+        mine = Tracer()
+        assert HubEndpoint(tracer=mine).tracer is mine
+        assert ReconcileServer(tracer=mine).tracer is mine
+    for name, tracer in _components():
+        assert tracer is NULL_TRACER, name
+
+
+def test_set_dispatch_tracer_is_the_process_tracer():
+    tr = Tracer()
+    engine.set_dispatch_tracer(tr)
+    try:
+        assert current_tracer() is tr
+        assert HubEndpoint().tracer is tr
+    finally:
+        engine.set_dispatch_tracer(None)
+    assert current_tracer() is NULL_TRACER
+    assert not hasattr(engine, "_DISPATCH_TRACER")
+
+
 def test_exports_roundtrip(tmp_path):
     tr = Tracer()
     with tr.span("a"):
@@ -479,8 +544,13 @@ def test_trace_report_sections(tmp_path):
     occ = rep["occupancy"]
     assert occ, "no occupancy rows"
     row = next(iter(occ.values()))
-    assert row["device_ms"] > 0 and row["wall_ms"] >= row["device_ms"]
-    assert 0 < row["device_frac"] <= 1
+    assert row["device_wait_ms"] > 0
+    assert row["wall_ms"] >= row["device_wait_ms"]
+    assert 0 < row["device_wait_frac"] <= 1
+    # device wait is the readback spans alone, not their host-work parents
+    waits = [e for e in tr.events() if e["name"] == "device.readback"]
+    assert waits
+    assert row["device_wait_ms"] <= sum(e["dur"] for e in waits) / 1e3 + 1e-9
 
     peers = rep["peers"]
     assert peers["local"]["sessions"] == 4

@@ -6,11 +6,12 @@ Reads either export format (the Chrome trace JSON that ``--trace`` /
 three sections:
 
 * **occupancy** — wall-clock split of the traced window into host work,
-  device work (``cat="device"`` spans: encode/decode dispatch and the
-  ``device_get`` collect waits), and wire waits (``cat="wire"`` spans:
-  round barriers, reply/outcome collection), per thread.  Overlapping
-  same-category spans on a thread are unioned, so nested spans don't
-  double-count.
+  device wait (``device.readback`` spans: host time blocked in
+  ``device_get`` until the chip's outputs are copied back; the device's
+  own busy time needs a profiler trace), and wire waits (``cat="wire"``
+  spans: round barriers, reply/outcome collection), per thread.
+  Overlapping same-category spans on a thread are unioned, so nested
+  spans don't double-count.
 * **per-peer traffic** — bytes, reconciled diff and rounds per session,
   grouped by peer/channel, from the ``session.result`` / ``peer.result``
   instants the endpoints emit at their freeze points.
@@ -53,7 +54,8 @@ def _union(intervals: list[tuple[float, float]]) -> float:
 
 
 def occupancy(events: list[dict]) -> dict:
-    """Host/device/wire split per thread, from the complete ("X") spans."""
+    """Host/device-wait/wire split per thread, from the complete ("X")
+    spans; device wait is the union of the ``device.readback`` spans."""
     spans = [e for e in events if e.get("ph") == "X"]
     names = {
         e["tid"]: e["args"]["name"]
@@ -62,7 +64,7 @@ def occupancy(events: list[dict]) -> dict:
     }
     by_tid: dict = defaultdict(lambda: defaultdict(list))
     for e in spans:
-        cat = e.get("cat", "host")
+        cat = "readback" if e["name"] == "device.readback" else e.get("cat", "host")
         by_tid[e["tid"]][cat].append((e["ts"], e["ts"] + e["dur"]))
     out = {}
     for tid, cats in by_tid.items():
@@ -70,15 +72,15 @@ def occupancy(events: list[dict]) -> dict:
         t0 = min(s for s, _ in allspans)
         t1 = max(e for _, e in allspans)
         wall = t1 - t0
-        device = _union(cats.get("device", []))
+        device_wait = _union(cats.get("readback", []))
         wire = _union(cats.get("wire", []))
         covered = _union(allspans)
         out[names.get(tid, str(tid))] = {
             "wall_ms": wall / 1e3,
-            "device_ms": device / 1e3,
+            "device_wait_ms": device_wait / 1e3,
             "wire_wait_ms": wire / 1e3,
-            "host_ms": (covered - device - wire) / 1e3,
-            "device_frac": device / wall if wall else 0.0,
+            "host_ms": (covered - device_wait - wire) / 1e3,
+            "device_wait_frac": device_wait / wall if wall else 0.0,
         }
     return out
 
@@ -170,8 +172,9 @@ def print_report(rep: dict) -> None:
     for name, o in rep["occupancy"].items():
         print(
             f"  {name:>24}: wall {o['wall_ms']:9.2f} ms | "
-            f"host {o['host_ms']:9.2f} | device {o['device_ms']:9.2f} "
-            f"({o['device_frac']:5.1%}) | wire wait {o['wire_wait_ms']:9.2f}"
+            f"host {o['host_ms']:9.2f} | device wait "
+            f"{o['device_wait_ms']:9.2f} ({o['device_wait_frac']:5.1%}) | "
+            f"wire wait {o['wire_wait_ms']:9.2f}"
         )
     if rep["peers"]:
         print("\n== per-peer traffic ==")
