@@ -232,7 +232,7 @@ class SessionState:
 
     a: np.ndarray
     b: np.ndarray
-    a_set: set
+    a_sorted: np.ndarray          # A sorted and unique (uint32), for in_sorted
     diff: set
     units: list
     next_uid: int
@@ -264,14 +264,27 @@ def new_session_state(a: np.ndarray, b: np.ndarray, plan: ProtocolPlan) -> Sessi
     with tracer.span("session.group_view", keys=len(a) + len(b)):
         grp_b, order_b, bounds_b = group_view(b, plan.g, plan.seed_groups)
         grp_a, order_a, bounds_a = group_view(a, plan.g, plan.seed_groups)
-    with tracer.span("session.member_set", keys=len(a)):
-        a_set = set(int(x) for x in a)
+    with tracer.span("session.member_set", keys=len(a)) as span:
+        # every served caller hands over A from np.unique: keep it as is
+        is_sorted = a.dtype == np.uint32 and bool(np.all(a[1:] > a[:-1]))
+        a_sorted = a if is_sorted else np.unique(np.asarray(a, dtype=np.uint32))
+        span.set(sorted=is_sorted)
     return SessionState(
-        a=a, b=b, a_set=a_set, diff=set(),
+        a=a, b=b, a_sorted=a_sorted, diff=set(),
         units=[Unit(uid=i, group=i) for i in range(plan.g)], next_uid=plan.g,
         group_b=grp_b, order_b=order_b, bounds_b=bounds_b,
         group_a=grp_a, order_a=order_a, bounds_a=bounds_a,
     )
+
+
+def in_sorted(a_sorted: np.ndarray, vals) -> np.ndarray:
+    """``np.isin(vals, a_sorted)`` for a sorted, unique uint32 ``a_sorted``,
+    by binary search: O(len(vals)·log|A|), with no re-sort of A."""
+    vals = np.asarray(vals, dtype=np.uint32)
+    if not len(a_sorted):
+        return np.zeros(len(vals), dtype=bool)
+    idx = np.minimum(np.searchsorted(a_sorted, vals), len(a_sorted) - 1)
+    return a_sorted[idx] == vals
 
 
 def effective_set(a: np.ndarray, diff: set) -> np.ndarray:
@@ -295,9 +308,7 @@ def diff_overlay(st: SessionState) -> tuple[np.ndarray, np.ndarray]:
         empty = np.zeros(0, dtype=np.uint32)
         return empty, empty
     d = np.fromiter(st.diff, dtype=np.uint32, count=len(st.diff))
-    # membership via the session's resident a_set: same split as
-    # np.isin(d, st.a) without re-sorting |A| elements every round
-    in_a = np.fromiter((int(v) in st.a_set for v in d), dtype=bool, count=len(d))
+    in_a = in_sorted(st.a_sorted, d)
     return d[in_a], d[~in_a]
 
 
@@ -501,8 +512,8 @@ def apply_round_outcomes(
                 st.fake_rejections += 1
                 continue
             newly.append(s)
-            in_eff = (s in st.a_set) ^ (s in st.diff)
-            delta_sum += -s if in_eff else s
+        for s, in_a in zip(newly, in_sorted(st.a_sorted, newly).tolist()):
+            delta_sum += -s if in_a ^ (s in st.diff) else s
         for s in newly:
             st.diff.symmetric_difference_update((s,))
         new_csum = int((int(csum_a[slot]) + delta_sum) % (1 << KEY_BITS))

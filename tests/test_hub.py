@@ -352,6 +352,8 @@ def test_hub_trace_spans_cover_and_nest():
     inside("device.readback", "hub.serve")
     inside("session.group_view", "session.state")
     inside("session.member_set", "session.state")
+    # every A on the served path arrives sorted: membership reuses it, no copy
+    assert all(s["args"]["sorted"] is True for s in by["session.member_set"])
     # each session state is built inside a peer's submit or the admission
     for s in by["session.state"]:
         assert any(_nested(s, p) for p in by["endpoint.submit"] + by["hub.admit"])
