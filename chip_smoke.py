@@ -20,7 +20,10 @@ runs in this one process: peers are threads, never child processes.
 4. oracle  -- success, diff, rounds and bytes per round of every session
               equal the reference; the tree peer's diff union equals A xor B;
 5. warm    -- phases 2 and 3 again on fresh servers and hubs, with zero
-              retraces.
+              retraces;
+6. outage  -- a hub serving 2 known-d peers at ten times the divergence
+              (d = 10^4 at the default sizes: the (511, 10) code over
+              GF(2^9), 2,000 units a session), equal to the reference.
 
 Times printed are smoke wall time on the host clock, compilation included:
 they are not benchmark metrics.  The script fails (exit code != 0, no
@@ -212,6 +215,42 @@ def check_oracle(label, got, expected) -> None:
                 )
 
 
+def run_outage_phase(args, deadline: float):
+    """Phase 6: a hub serving 2 known-d peers at ten times the divergence,
+    each held to ``core.pbs.reconcile``."""
+    from repro.core.pbs import PBSConfig, plan_from_d_known, reconcile
+    from repro.core.simdata import make_pair_two_sided
+    from repro.net import AliceEndpoint, HubEndpoint, InMemoryDuplex, run_hub
+
+    d = 10 * args.d
+    hub = HubEndpoint(recv_deadline=deadline)
+    alices, cases = {}, []
+    for i in range(2):
+        rng = np.random.default_rng([args.seed, 100 + i])
+        a, b = make_pair_two_sided(args.keys, d // 2, d - d // 2, rng)
+        cfg = PBSConfig(seed=args.seed * 16 + 8 + i)
+        ta, tb = InMemoryDuplex.pair()
+        ch = hub.add_peer(tb, label=f"outage{i}")
+        hub.submit(ch, b, cfg=cfg, d_known=d)
+        ep = AliceEndpoint(ta, channel=ch)
+        ep.submit(a, cfg=cfg, d_known=d)
+        alices[ch] = ep
+        cases.append(Case("outage", a, b, cfg, d))
+    plan = plan_from_d_known(cases[0].cfg, d)
+    t0 = time.perf_counter()
+    outcomes, results, errors = run_hub(hub, alices, join_timeout=deadline)
+    wall = time.perf_counter() - t0
+    assert not errors, f"peer endpoints raised: {errors}"
+    failed = {ch: (o.error_kind, o.error) for ch, o in outcomes.items() if not o.ok}
+    assert not failed, f"hub evicted peers: {failed}"
+    expected = [reconcile(c.a, c.b, c.cfg, d_known=d) for c in cases]
+    check_oracle("hub/outage", [results[ch][0] for ch in alices], expected)
+    st = hub.stats
+    log(f"phase hub outage: smoke wall time {wall:.3f} s, peers=2 d={d} "
+        f"n={plan.n} t={plan.t} units={plan.g} per session, "
+        f"rounds={st['rounds']} kernel_launches={st['kernel_launches']}")
+
+
 def check_tree(tree_results, tree: Case) -> None:
     from repro.core.pbs import true_diff
 
@@ -279,6 +318,7 @@ def main(argv=None) -> int:
         else:
             assert st["retraces"] == 0, f"warm engine retraced {st['retraces']}"
             assert hst["retraces"] == 0, f"warm hub retraced {hst['retraces']}"
+    run_outage_phase(args, args.deadline)
     log("phase oracle: every engine and hub session equals core.pbs.reconcile; "
         "tree peer diff union equals A xor B")
 

@@ -10,8 +10,10 @@ bits(E) ∈ {0,1}^(tile × 33) (32 key bits ‖ ones column for counting),
 then `acc & 1` yields per-bin XOR folds (bit-parity == XOR) and the parity
 bitmap (count parity) in one shot.  The grid walks element tiles; `acc`
 lives in VMEM scratch for the whole pass.  The batched kernel keeps keys on
-the lane axis and computes the same product as Hᵀ (n × tile) against bitsᵀ
-(33 × tile), contracted over lanes, with bf16 0/1 operands.
+the lane axis and accumulates the transpose, bitsᵀ (33 × tile) against Hᵀ
+(n × tile) contracted over lanes into (33 × n), with bf16 0/1 operands;
+it folds the 32 bit planes into finished uint32 words before writing, so
+its outputs are lane-dense (U, n) arrays, bins on lanes, 8 units to a tile.
 
 Two binning reductions are provided (both keyed by murmur-finalizer mix32):
 
@@ -125,17 +127,29 @@ def bin_parity_xorsum(
     return parity, xor_bits
 
 
-def _units_kernel(seeds_ref, elems_ref, valid_ref, o_ref, acc_ref, *, n_bins: int, nt: int):
-    """Grid (U, nt): per unit u, walk its element tiles accumulating Hᵀ @ bits.
+# Units per output block of the batched kernel: its (8, n) output blocks
+# fill the 8 sublanes of a tile, so no output row is padded.
+UNITS_PER_BLOCK = 8
 
-    Elements ride the lane axis as a ``(1, tile)`` row, so the one-hot is
-    built transposed, ``(n, tile)``, and the bit planes as ``(33, tile)``;
-    the MXU contracts both over the lane axis.  0/1 operands are exact in
-    bf16 and every per-tile count (≤ tile ≤ 1024) is exact in the f32
+
+def _units_kernel(seeds_ref, elems_ref, valid_ref, parity_ref, xor_ref, acc_ref, *,
+                  n_bins: int, nt: int):
+    """Grid (U / 8, 8, nt): unit 8g + j walks its element tiles accumulating
+    bitsᵀ @ H, then writes its finished words into row j of the (8, n)
+    output blocks, which stay in VMEM while g is unchanged.
+
+    Elements ride the lane axis as a ``(1, tile)`` row; the one-hot is
+    built as ``(n, tile)`` and the bit planes as ``(33, tile)``, and the
+    MXU contracts both over the lane axis into a ``(33, n)`` count: bit
+    planes on sublanes, bins on lanes.  0/1 operands are exact in bf16
+    and every per-tile count (≤ tile ≤ 1024) is exact in the f32
     accumulator, so the int32 running sum is bit-identical to integer math.
+    ``_emit`` folds rows 0..31 (mod 2) into one 32-bit XOR word per bin
+    with shifts and ORs, and row 32 (mod 2) is the parity bitmap.
     """
-    u = pl.program_id(0)
-    ti = pl.program_id(1)
+    g = pl.program_id(0)
+    j = pl.program_id(1)
+    ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _init():
@@ -143,24 +157,30 @@ def _units_kernel(seeds_ref, elems_ref, valid_ref, o_ref, acc_ref, *, n_bins: in
 
     e = elems_ref[...].astype(jnp.uint32)      # (1, tile)
     valid = valid_ref[...] > 0                 # (1, tile)
-    seed = seeds_ref[u].astype(jnp.uint32)     # this unit's bin seed, from SMEM
+    seed = seeds_ref[g * UNITS_PER_BLOCK + j].astype(jnp.uint32)  # from SMEM
     bins = mulshift_bins(mix32_jnp(e, seed), n_bins)
     hit = (jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0) == bins) & valid
     onehot = jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)  # (n, tile)
     planes = jax.lax.broadcasted_iota(jnp.int32, (33, 1), 0)
     shifts = jnp.minimum(planes, 31).astype(jnp.uint32)
     key_bits = ((e >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-    # rows 0..31: key bit planes; row 32: the ones column (valid) for counting
+    # rows 0..31: key bit planes; row 32: the ones row (valid) for counting
     bit = jnp.where(planes == 32, valid.astype(jnp.int32), key_bits)
     bits = bit.astype(jnp.float32).astype(jnp.bfloat16)
     counts = jax.lax.dot_general(
-        onehot, bits, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                          # (n, 33)
+        bits, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )                                          # (33, n)
     acc_ref[...] += counts.astype(jnp.int32)
 
     @pl.when(ti == nt - 1)
     def _emit():
-        o_ref[...] = acc_ref[...] & 1
+        odd = acc_ref[...] & 1                                   # (33, n)
+        words = odd[0:32, :] << planes[0:32]                     # plane r to bit r
+        for half in (16, 8, 4, 2, 1):                            # OR-fold 32 -> 1
+            words = words[:half, :] | words[half:, :]
+        mine = jax.lax.broadcasted_iota(jnp.int32, (UNITS_PER_BLOCK, 1), 0) == j
+        parity_ref[...] = jnp.where(mine, odd[32:33, :], parity_ref[...])
+        xor_ref[...] = jnp.where(mine, words, xor_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "tile", "interpret"))
@@ -179,7 +199,8 @@ def bin_parity_xorsum_units(
     ``seeds``: (U,) uint32 per-unit binning seeds (sessions derive different
     seeds, so units of many sessions pack into one launch — DESIGN.md §5).
     Bins with the protocol's multiply-shift hash (``hash_to_range``).
-    Returns (parity (U, n_bins) int32, xor_bits (U, n_bins, 32) int32).
+    Returns (parity (U, n_bins) int32, xors (U, n_bins) uint32), lane-dense:
+    bins on the lane axis, 8 units to a tile of sublanes.
     """
     interpret = resolve_interpret(interpret)
     e = elems.astype(jnp.uint32)
@@ -187,27 +208,29 @@ def bin_parity_xorsum_units(
     if tile is None:  # smallest lane-aligned tile covering typical unit loads
         tile = max(128, min(1024, ceil_to(E, 128)))
     Ep = max(tile, ceil_to(E, tile))
-    pad = Ep - E
-    # (U, 1, Ep) rows: a (1, tile) block spans the full second-minor dim
-    e_p = jnp.pad(e, ((0, 0), (0, pad)))[:, None, :]
-    v_p = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))[:, None, :]
+    Up = ceil_to(U, UNITS_PER_BLOCK)
+    pads = ((0, Up - U), (0, Ep - E))
+    # (Up, 1, Ep) rows: a (1, tile) block spans the full second-minor dim
+    e_p = jnp.pad(e, pads)[:, None, :]
+    v_p = jnp.pad(valid.astype(jnp.int32), pads)[:, None, :]
+    s_p = jnp.pad(jax.lax.bitcast_convert_type(seeds.astype(jnp.uint32), jnp.int32),
+                  (0, Up - U))
     nt = Ep // tile
-    out = pl.pallas_call(
+    row = pl.BlockSpec((None, 1, tile), lambda g, j, i, s: (g * UNITS_PER_BLOCK + j, 0, i))
+    words = pl.BlockSpec((UNITS_PER_BLOCK, n_bins), lambda g, j, i, s: (g, 0))
+    parity, xors = pl.pallas_call(
         functools.partial(_units_kernel, n_bins=n_bins, nt=nt),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(U, nt),
-            in_specs=[
-                pl.BlockSpec((None, 1, tile), lambda u, i, s: (u, 0, i)),
-                pl.BlockSpec((None, 1, tile), lambda u, i, s: (u, 0, i)),
-            ],
-            out_specs=pl.BlockSpec((None, n_bins, 33), lambda u, i, s: (u, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((n_bins, 33), jnp.int32)],
+            grid=(Up // UNITS_PER_BLOCK, UNITS_PER_BLOCK, nt),
+            in_specs=[row, row],
+            out_specs=[words, words],
+            scratch_shapes=[pltpu.VMEM((33, n_bins), jnp.int32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((U, n_bins, 33), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((Up, n_bins), jnp.int32)] * 2,
         interpret=interpret,
-    )(jax.lax.bitcast_convert_type(seeds.astype(jnp.uint32), jnp.int32), e_p, v_p)
-    return out[:, :, 32], out[:, :, :32]
+    )(s_p, e_p, v_p)
+    return parity[:U], jax.lax.bitcast_convert_type(xors[:U], jnp.uint32)
 
 
 def xor_bits_to_u32(xor_bits: jax.Array) -> jax.Array:

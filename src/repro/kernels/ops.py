@@ -96,11 +96,11 @@ def encode_groups(
 
     Returns (parity (U, n), xors (U, n) uint32, sketches (U, t)).
     """
-    parity, xor_bits = bin_parity_xorsum_units(
+    parity, xors = bin_parity_xorsum_units(
         elems, valid, seeds, n_bins=code.n, interpret=interpret
     )
     sketches = sketch_groups(parity, code, interpret=interpret)
-    return parity, xor_bits_to_u32(xor_bits), sketches
+    return parity, xors, sketches
 
 
 def tow_estimate(elems_a: jax.Array, elems_b: jax.Array, seeds: jax.Array, *, interpret=None):

@@ -380,6 +380,7 @@ def decode_side_b_round(
         if launches is not None:
             launches["decode_launches"] = launches.get("decode_launches", 0) + 1
         inflight.append((plan, out))
+    tracer = tracer if tracer is not None else current_tracer()
     results: dict[int, tuple] = {}
     ctx: dict[int, tuple] = {}
     for plan, out in inflight:
@@ -389,29 +390,33 @@ def decode_side_b_round(
         # writable: the rateless ladder merges extension verdicts into the
         # per-session ok views in place (DESIGN.md §16)
         ok_pad = np.array(ok_pad)
-        for sess, base, active, bin_seed in plan.members:
-            if sess.sid not in sk_a_of:
-                continue
-            rows = slice(base, base + len(active))
-            row = per[sess.sid]
-            ok = ok_pad[rows]
-            pos, cnt = pos_pad[rows], cnt_pad[rows]
-            units: list[ReplyUnit | None] = []
-            for slot in range(len(active)):
-                if not ok[slot]:
-                    units.append(None)
+        with tracer.span("decode.reply_units", n=plan.store.n) as span:
+            n_units = 0
+            for sess, base, active, bin_seed in plan.members:
+                if sess.sid not in sk_a_of:
                     continue
-                k = int(cnt[slot])
-                p = pos[slot, :k].astype(np.int64)
-                units.append(
-                    ReplyUnit(
-                        positions=p,
-                        xors=row.xors[slot, p],
-                        csum=int(row.csum[slot]),
+                rows = slice(base, base + len(active))
+                row = per[sess.sid]
+                ok = ok_pad[rows]
+                pos, cnt = pos_pad[rows], cnt_pad[rows]
+                units: list[ReplyUnit | None] = []
+                for slot in range(len(active)):
+                    if not ok[slot]:
+                        units.append(None)
+                        continue
+                    k = int(cnt[slot])
+                    p = pos[slot, :k].astype(np.int64)
+                    units.append(
+                        ReplyUnit(
+                            positions=p,
+                            xors=row.xors[slot, p],
+                            csum=int(row.csum[slot]),
+                        )
                     )
-                )
-            results[sess.sid] = (ok, units)
-            ctx[sess.sid] = (sess, active, ok, bin_seed)
+                n_units += len(active)
+                results[sess.sid] = (ok, units)
+                ctx[sess.sid] = (sess, active, ok, bin_seed)
+            span.set(units=n_units)
     return results, ctx
 
 

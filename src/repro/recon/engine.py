@@ -37,12 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bch import bch_code
-from repro.kernels.bin_xorsum import (
-    bin_parity_xorsum_units,
-    mix32_jnp,
-    mulshift_bins,
-    xor_bits_to_u32,
-)
+from repro.kernels.bin_xorsum import bin_parity_xorsum_units, mix32_jnp, mulshift_bins
 from repro.kernels.ops import bch_decode_batched, sketch_groups, sketch_groups_range
 from repro.kernels.platform import count_retrace
 from repro.obs.trace import current_tracer, set_tracer
@@ -58,10 +53,13 @@ set_dispatch_tracer = set_tracer
 def readback(out, what: str, tracer=None):
     """Wait for the device outputs ``out`` and copy them to the host, under
     the ``device.readback`` span: the one ``cat="device"`` span, so a trace
-    tells host time blocked on the chip from the host work around it."""
+    tells host time blocked on the chip from the host work around it.  Its
+    ``bytes`` arg is what was copied."""
     tracer = tracer if tracer is not None else current_tracer()
-    with tracer.span("device.readback", cat="device", what=what):
-        return jax.device_get(out)
+    with tracer.span("device.readback", cat="device", what=what) as span:
+        host = jax.device_get(out)
+        span.set(bytes=sum(x.nbytes for x in jax.tree_util.tree_leaves(host)))
+        return host
 
 
 def _count_trace(name: str, probe) -> None:
@@ -194,11 +192,10 @@ def _execute_round(
     elems2 = jnp.concatenate([ea, eb], axis=0)          # (2U, W)
     valid2 = jnp.concatenate([va, vb], axis=0)
     seeds2 = jnp.concatenate([seeds, seeds], axis=0)
-    parity2, xor_bits2 = bin_parity_xorsum_units(
+    parity2, xors2 = bin_parity_xorsum_units(
         elems2, valid2.astype(jnp.int32), seeds2, n_bins=n, interpret=interpret
     )
     sk2 = sketch_groups(parity2, code, interpret=interpret)
-    xors2 = xor_bits_to_u32(xor_bits2)
     csum2 = _wrap_csum(elems2, valid2)
 
     u = row_map.shape[0]
@@ -245,11 +242,11 @@ def _encode_side(
         flat, start, cnt, row_map, width,
         removed, removed_cnt, added, added_cnt, unit_valid, fseeds, fbins, fcnt,
     )
-    parity, xor_bits = bin_parity_xorsum_units(
+    parity, xors = bin_parity_xorsum_units(
         e, v.astype(jnp.int32), seeds, n_bins=n, interpret=interpret
     )
     sk = sketch_groups(parity, code, interpret=interpret)
-    return sk, xor_bits_to_u32(xor_bits), _wrap_csum(e, v)
+    return sk, xors, _wrap_csum(e, v)
 
 
 def _execute_round_ext(

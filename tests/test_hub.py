@@ -239,6 +239,40 @@ def test_hub_peer_joining_between_rounds_is_byte_identical():
     assert outcomes[ch2].sessions[0].rnd0 >= 1  # really joined mid-run
 
 
+def test_hub_outage_code_matches_oracle():
+    """The outage cell's code through the served hub: 3 peers of 2·10^4
+    keys per side at d = 1%, pinned to the (511, 10) BCH code over GF(2^9)
+    that the planner picks at d = 10^4.  Every result equals
+    ``core.pbs.reconcile`` field for field."""
+    keys, d = 20_000, 200
+    hub = HubEndpoint(recv_deadline=60.0)
+    alices, cases = {}, {}
+    for i in range(3):
+        a, b = make_pair_two_sided(keys, d // 2, d - d // 2,
+                                   np.random.default_rng(500 + i))
+        cfg = PBSConfig(seed=90 + i, n_override=511, t_override=10)
+        ta, tb = InMemoryDuplex.pair()
+        ch = hub.add_peer(tb)
+        hub.submit(ch, b, cfg=cfg, d_known=d)
+        ep = AliceEndpoint(ta, channel=ch)
+        ep.submit(a, cfg=cfg, d_known=d)
+        alices[ch] = ep
+        cases[ch] = (a, b, cfg)
+    outcomes, results, errors = run_hub(hub, alices)
+    assert not errors
+    for ch, (a, b, cfg) in cases.items():
+        exp = reconcile(a, b, cfg, d_known=d)
+        got = results[ch][0]
+        assert (got.n, got.t) == (exp.n, exp.t) == (511, 10)
+        assert got.diff == exp.diff == true_diff(a, b), ch
+        assert got.rounds == exp.rounds, ch
+        assert got.bytes_per_round == exp.bytes_per_round, ch
+        assert got.bytes_sent == exp.bytes_sent, ch
+        assert got.success and exp.success, ch
+        assert got.decode_failures == exp.decode_failures, ch
+        assert outcomes[ch].ok and outcomes[ch].verified == [True], ch
+
+
 def test_hub_rejects_wrong_and_stale_channel_ids():
     """A frame tagged with any channel other than the peer's own — unknown,
     someone else's, or a retired (stale) one — evicts only that peer."""
@@ -328,7 +362,8 @@ def test_hub_trace_spans_cover_and_nest():
     for name in ("endpoint.submit", "hub.submit", "hub.serve", "hub.admit",
                  "session.state", "session.group_view", "session.member_set",
                  "hub.plan_round", "store.build", "store.layout",
-                 "store.upload", "device.readback", "hub.join_peers"):
+                 "store.upload", "device.readback", "decode.reply_units",
+                 "hub.join_peers"):
         assert name in by, name
     assert len(by["endpoint.submit"]) == len(by["hub.submit"]) == 3
     assert by["hub.admit"][0]["args"] == {"peers": 3, "sessions": 3}
@@ -350,6 +385,7 @@ def test_hub_trace_spans_cover_and_nest():
     inside("hub.plan_round", "hub.serve")
     inside("hub.admit", "hub.serve")
     inside("device.readback", "hub.serve")
+    inside("decode.reply_units", "hub.decode")
     inside("session.group_view", "session.state")
     inside("session.member_set", "session.state")
     # every A on the served path arrives sorted: membership reuses it, no copy
@@ -360,6 +396,14 @@ def test_hub_trace_spans_cover_and_nest():
     assert {s["args"]["side"] for s in by["session.state"]} == {"a", "b"}
     assert all(r["args"]["what"] in ("encode", "decode")
                for r in by["device.readback"])
+    # every readback says what it copied: round 1's encode brings back
+    # each unit's n XOR words at least
+    assert all(r["args"]["bytes"] > 0 for r in by["device.readback"])
+    # (3 sessions of 2 units at n = 63 in round 1)
+    assert max(r["args"]["bytes"] for r in by["device.readback"]) >= 4 * 63 * 6
+    # the reply loop covers every unit of its cohort that was decoded
+    assert {r["args"]["n"] for r in by["decode.reply_units"]} == {63}
+    assert by["decode.reply_units"][0]["args"]["units"] == 6
 
     # after the last submit, serve and the joins cover the run but for
     # starting the peer threads (a few ms while they take the GIL)

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bch import BCHCode, batched_decode, sketch_from_positions
+from repro.core.pbs import unit_tables
 from repro.kernels import ref
 from repro.kernels.bin_xorsum import (
     bin_parity_xorsum,
@@ -101,12 +102,41 @@ def test_bin_units_layouts(units, width, n_bins):
     # seeds above 2^31 exercise the int32 bitcast through scalar memory
     seeds = rng.integers(0, 1 << 32, size=units, dtype=np.uint64).astype(np.uint32)
     seeds[0] = 0xFFFFFFFF
-    parity, xor_bits = bin_parity_xorsum_units(
+    parity, xors = bin_parity_xorsum_units(
         jnp.array(elems), jnp.array(valid), jnp.array(seeds), n_bins=n_bins
     )
     p_ref, x_ref = ref.bin_parity_xorsum_units_ref(elems, valid, seeds, n_bins)
     np.testing.assert_array_equal(np.array(parity), p_ref)
-    np.testing.assert_array_equal(np.array(xor_bits_to_u32(xor_bits)), x_ref)
+    np.testing.assert_array_equal(np.array(xors), x_ref)
+
+
+@pytest.mark.parametrize("n_bins", [255, 511, 1023, 2047])
+def test_bin_units_words_match_unit_tables(n_bins):
+    """The lane-dense outputs, finished parity and XOR words per (unit,
+    bin), equal the protocol's own tables (``core.pbs.unit_tables``) unit
+    by unit: 11 units (one block of 8 and a padded second), ragged valid
+    prefixes, invalid lanes holding keys, each unit on its own seed."""
+    rng = np.random.default_rng(n_bins)
+    units, width = 11, 700
+    elems, valid = _ragged_rows(rng, units, width)
+    valid[3, ::3] = 0                    # holes inside a row, not only a tail
+    seeds = rng.integers(0, 1 << 32, size=units, dtype=np.uint64).astype(np.uint32)
+    parity, xors = bin_parity_xorsum_units(
+        jnp.array(elems), jnp.array(valid), jnp.array(seeds), n_bins=n_bins,
+        interpret=True,
+    )
+    assert parity.shape == xors.shape == (units, n_bins)
+    assert parity.dtype == jnp.int32 and xors.dtype == jnp.uint32
+    parity, xors = np.asarray(parity), np.asarray(xors)
+    for u in range(units):
+        idx = np.flatnonzero(valid[u])
+        slot, pos, x_ref, _ = unit_tables(
+            elems[u], idx, np.zeros(len(idx), np.int64), 1, n_bins, int(seeds[u])
+        )
+        p_ref = np.zeros(n_bins, np.int32)
+        p_ref[pos] = 1
+        np.testing.assert_array_equal(parity[u], p_ref, err_msg=f"unit {u}")
+        np.testing.assert_array_equal(xors[u], x_ref[0], err_msg=f"unit {u}")
 
 
 @pytest.mark.parametrize("rows,width", [(3, 40), (9, 700)])

@@ -33,6 +33,12 @@ N_BINS, T = 255, 8
 UNITS, WIDTH = 512, 8192
 STORE, ROWS = 2_000_128, 400
 OVERLAY, FILTERS = 8, 1
+# The hub's round 1 in the benchmark's outage cell: 32 streams at
+# d = 10^4 plan the (511, 10) code with 2,000 units each, 64,000 units
+# bucketed to 65,536, rows of about 500 keys bucketed to 1,024, and a
+# resident store of 32 x 10^6 keys.
+OUTAGE = {"units": 65_536, "n": 511, "t": 10, "width": 1024,
+          "store": 32_000_128, "rows": 64_000}
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +101,7 @@ def test_sketch_groups_range_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("n_bins", [N_BINS, 1023])
+@pytest.mark.parametrize("n_bins", [N_BINS, 511, 1023])
 def test_bin_parity_xorsum_units_compiles(one_chip, n_bins):
     text = _compiled_text(
         lambda e, v, s: bin_parity_xorsum_units(e, v, s, n_bins=n_bins, interpret=False),
@@ -133,26 +139,26 @@ def test_bch_decode_batched_compiles(one_chip):
     )
 
 
-def _store(sharding):
+def _store(sharding, store=STORE, rows=ROWS):
     return [
-        _spec(sharding, (STORE,), jnp.uint32),
-        _spec(sharding, (ROWS,), jnp.int32),
-        _spec(sharding, (ROWS,), jnp.int32),
+        _spec(sharding, (store,), jnp.uint32),
+        _spec(sharding, (rows,), jnp.int32),
+        _spec(sharding, (rows,), jnp.int32),
     ]
 
 
-def _round_arrays(sharding):
-    u = (UNITS,)
+def _round_arrays(sharding, units=UNITS):
+    u = (units,)
     return [
         _spec(sharding, u, jnp.int32),                      # row_map
         _spec(sharding, u, jnp.int32),                      # unit_valid
         _spec(sharding, u, jnp.uint32),                     # seeds
-        _spec(sharding, (UNITS, OVERLAY), jnp.uint32),      # removed
+        _spec(sharding, (units, OVERLAY), jnp.uint32),      # removed
         _spec(sharding, u, jnp.int32),                      # removed_cnt
-        _spec(sharding, (UNITS, OVERLAY), jnp.uint32),      # added
+        _spec(sharding, (units, OVERLAY), jnp.uint32),      # added
         _spec(sharding, u, jnp.int32),                      # added_cnt
-        _spec(sharding, (UNITS, FILTERS), jnp.uint32),      # fseeds
-        _spec(sharding, (UNITS, FILTERS), jnp.int32),       # fbins
+        _spec(sharding, (units, FILTERS), jnp.uint32),      # fseeds
+        _spec(sharding, (units, FILTERS), jnp.int32),       # fbins
         _spec(sharding, u, jnp.int32),                      # fcnt
     ]
 
@@ -173,3 +179,23 @@ def test_round_executor_compiles(one_chip, executor):
         n=N_BINS, interpret=False, **codes, **widths,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("executor", ["_encode_side", "_execute_round"])
+def test_round_executor_fits_the_outage_cell(one_chip, executor):
+    """Round 1 of the outage cell at its real sizes fits one v5e with room
+    to spare: the binning output is lane-dense, (U, n) words with bins on
+    lanes, so no (U, n, 33) bit-plane array padded to 128 lanes is made."""
+    both_sides = executor == "_execute_round"
+    store = _store(one_chip, OUTAGE["store"], OUTAGE["rows"])
+    width = OUTAGE["width"]
+    widths = {"width_a": width, "width_b": width} if both_sides else {"width": width}
+    jitted = jax.jit(getattr(engine, executor),
+                     static_argnames=("n", "t", "interpret", *widths))
+    compiled = jitted.lower(
+        *store, *(store if both_sides else []),
+        *_round_arrays(one_chip, OUTAGE["units"]),
+        n=OUTAGE["n"], t=OUTAGE["t"], interpret=False, **widths,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
