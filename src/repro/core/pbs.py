@@ -246,6 +246,7 @@ class SessionState:
     rounds: int = 0
     decode_failures: int = 0
     fake_rejections: int = 0
+    recovered: int = 0            # values added to or removed from D̂
 
     def active_units(self) -> list:
         return [u for u in self.units if not u.done]
@@ -484,42 +485,64 @@ def apply_round_outcomes(
     the Alice->Bob sketches — and the per-slot checksum-settled flags that
     the endpoint path ships to Bob as the round-outcome frame so he can
     mirror the unit queue.
+
+    Whole-round array operations over every decoded bin at once (DESIGN.md
+    §2).  Bins partition a unit, and the active units partition the
+    universe (``queue_split`` retires a parent as it enqueues its
+    children), so no value passes Procedure 3 in two slots, and D̂ as it
+    stood before the round decides every sign.  Only a malformed reply
+    recovers a value twice, by repeating a position: each copy counts with
+    that one sign and toggles D̂ once, as a per-difference walk would.
     """
     cfg, n, g, m = plan.cfg, plan.n, plan.g, plan.m
-    bits = 0
+    ok = np.asarray(ok, dtype=bool)
     done = [False] * len(active)
-    for slot, u in enumerate(active):
-        if not ok[slot]:
-            queue_split(st, u, rnd, cfg.seed)
-            continue
-        pos = positions[slot]
-        # Bob -> Alice: bin indices, his XOR sums, his checksum (Formula 1).
-        bits += len(pos) * (m + KEY_BITS) + KEY_BITS
-        delta_sum = 0
-        newly = []
-        for p in pos:
-            s = int(xors_a[slot, int(p)] ^ xors_b[slot, int(p)])
-            if s == 0:
-                st.fake_rejections += 1
-                continue
-            sx = np.array([s], dtype=np.uint32)
-            # Procedure 3: s must belong to this unit's sub-universe.
-            if (
-                int(hash_to_range(sx, n, bin_seed)[0]) != int(p)
-                or int(hash_to_range(sx, g, plan.seed_groups)[0]) != u.group
-                or any(int(hash_to_range(sx, 3, fs)[0]) != fk for fs, fk in u.filters)
-            ):
-                st.fake_rejections += 1
-                continue
-            newly.append(s)
-        for s, in_a in zip(newly, in_sorted(st.a_sorted, newly).tolist()):
-            delta_sum += -s if in_a ^ (s in st.diff) else s
-        for s in newly:
-            st.diff.symmetric_difference_update((s,))
-        new_csum = int((int(csum_a[slot]) + delta_sum) % (1 << KEY_BITS))
-        if new_csum == int(csum_b[slot]):
-            u.done = True
-            done[slot] = True
+    for slot in np.flatnonzero(~ok):
+        queue_split(st, active[slot], rnd, cfg.seed)
+    live = np.flatnonzero(ok)
+    pos_of = [np.asarray(positions[slot], dtype=np.int64) for slot in live]
+    counts = np.array([len(p) for p in pos_of], dtype=np.int64)
+    # Bob -> Alice: bin indices, his XOR sums, his checksum (Formula 1).
+    bits = int(counts.sum()) * (m + KEY_BITS) + len(live) * KEY_BITS
+    if not len(live):
+        return bits, done
+    bounds = np.concatenate([[0], np.cumsum(counts)])
+    slot_of = np.repeat(live, counts)
+    pos = np.concatenate(pos_of)
+    s = (np.asarray(xors_a)[slot_of, pos] ^ np.asarray(xors_b)[slot_of, pos]).astype(np.uint32)
+
+    # Procedure 3: s must belong to this unit's sub-universe.
+    units = [active[slot] for slot in live]
+    group = np.repeat(np.array([u.group for u in units], dtype=np.int64), counts)
+    keep = (
+        (s != 0)
+        & (hash_to_range(s, n, bin_seed) == pos)
+        & (hash_to_range(s, g, plan.seed_groups) == group)
+    )
+    for i, u in enumerate(units):
+        for fs, fk in u.filters:
+            lo, hi = bounds[i], bounds[i + 1]
+            keep[lo:hi] &= hash_to_range(s[lo:hi], 3, fs) == fk
+    st.fake_rejections += int(len(s) - keep.sum())
+
+    # the sign of each recovered value: minus where it leaves A △ D̂
+    vals = s[keep]
+    in_d = np.fromiter((v in st.diff for v in vals.tolist()), dtype=bool, count=len(vals))
+    signed = np.zeros(len(s), dtype=np.int64)
+    signed[keep] = np.where(in_sorted(st.a_sorted, vals) ^ in_d, -1, 1) * vals.astype(np.int64)
+    running = np.concatenate([[0], np.cumsum(signed)])
+    delta = running[bounds[1:]] - running[bounds[:-1]]
+    # a position repeated in one reply toggles D̂ once per copy
+    uniq, copies = np.unique(vals, return_counts=True)
+    toggled = uniq[copies % 2 == 1].tolist()
+    st.diff.symmetric_difference_update(toggled)
+    st.recovered += len(toggled)
+
+    new_csum = (np.asarray(csum_a)[live].astype(np.int64) + delta) % (1 << KEY_BITS)
+    settled = new_csum.astype(np.uint64) == np.asarray(csum_b)[live].astype(np.uint64)
+    for slot in live[settled].tolist():
+        active[slot].done = True
+        done[slot] = True
     return bits, done
 
 

@@ -920,42 +920,58 @@ class AliceEndpoint(_Endpoint):
             )
 
             done_lists = []
-            for sid in live:
-                ok, units = ent_of[sid]
-                row = per[sid]
-                st, plan = row.sess.state, row.sess.plan
-                rloc = rnd - row.sess.rnd0   # local protocol round
-                u_cnt = len(row.active)
-                n, t, m = plan.n, plan.t, plan.m
-                xors_b = np.zeros((u_cnt, n), dtype=np.uint32)
-                csum_b = np.zeros(u_cnt, dtype=np.uint64)
-                positions = []
-                for slot in range(u_cnt):
-                    unit = units[slot]
-                    if unit is None:
-                        positions.append(np.zeros(0, dtype=np.int64))
-                        continue
-                    positions.append(unit.positions)
-                    xors_b[slot, unit.positions] = unit.xors
-                    csum_b[slot] = unit.csum
-                reply_bits, done = apply_round_outcomes(
-                    st, row.active, ok, positions,
-                    row.xors, xors_b, row.csum, csum_b,
-                    plan=plan, bin_seed=row.bin_seed, rnd=rloc,
-                )
-                # the measured ledger: sketch bits from what we framed,
-                # reply + parity bits from what the frames actually carried
-                # — must land exactly on the Formula-(1) accounting
-                measured = measured_of[sid] + measured_ext[sid]
-                accounted = u_cnt * (t * m + 1) + reply_bits + ext_bits_of[sid]
-                if measured != accounted:
-                    raise WireError(
-                        f"sid {sid} round {rnd}: measured {measured} bits != "
-                        f"accounted {accounted}"
+            states = [per[sid].sess.state for sid in live]
+            recovered0 = sum(st.recovered for st in states)
+            fakes0 = sum(st.fake_rejections for st in states)
+            with tracer.span("round.apply_outcomes", round=rnd,
+                             sessions=len(live)) as span:
+                for sid in live:
+                    ok, units = ent_of[sid]
+                    row = per[sid]
+                    st, plan = row.sess.state, row.sess.plan
+                    rloc = rnd - row.sess.rnd0   # local protocol round
+                    u_cnt = len(row.active)
+                    n, t, m = plan.n, plan.t, plan.m
+                    xors_b = np.zeros((u_cnt, n), dtype=np.uint32)
+                    csum_b = np.zeros(u_cnt, dtype=np.uint64)
+                    positions = []
+                    for slot in range(u_cnt):
+                        unit = units[slot]
+                        if unit is None:
+                            positions.append(np.zeros(0, dtype=np.int64))
+                            continue
+                        positions.append(unit.positions)
+                        xors_b[slot, unit.positions] = unit.xors
+                        csum_b[slot] = unit.csum
+                    reply_bits, done = apply_round_outcomes(
+                        st, row.active, ok, positions,
+                        row.xors, xors_b, row.csum, csum_b,
+                        plan=plan, bin_seed=row.bin_seed, rnd=rloc,
                     )
-                st.bytes_per_round.append((measured + 7) // 8)
-                st.rounds = rloc
-                done_lists.append(done)
+                    # the measured ledger: sketch bits from what we framed,
+                    # reply + parity bits from what the frames actually carried
+                    # — must land exactly on the Formula-(1) accounting
+                    measured = measured_of[sid] + measured_ext[sid]
+                    accounted = u_cnt * (t * m + 1) + reply_bits + ext_bits_of[sid]
+                    if measured != accounted:
+                        raise WireError(
+                            f"sid {sid} round {rnd}: measured {measured} bits != "
+                            f"accounted {accounted}"
+                        )
+                    st.bytes_per_round.append((measured + 7) // 8)
+                    st.rounds = rloc
+                    done_lists.append(done)
+                if tracer.enabled:
+                    span.set(
+                        recovered=sum(st.recovered for st in states) - recovered0,
+                        fake_rejections=sum(st.fake_rejections for st in states)
+                        - fakes0,
+                        filtered_slots=sum(
+                            1 for sid in live
+                            for u, good in zip(per[sid].active, ent_of[sid][0])
+                            if good and u.filters
+                        ),
+                    )
 
             out_frame = wf.encode_round_outcome(rnd, done_lists)
             # commit the barrier BEFORE the send: local state is complete, so

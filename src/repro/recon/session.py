@@ -918,6 +918,7 @@ def escalate_session(
     sess.state.bytes_per_round = old_state.bytes_per_round
     sess.state.decode_failures = old_state.decode_failures
     sess.state.fake_rejections = old_state.fake_rejections
+    sess.state.recovered = old_state.recovered
     sess.rnd0 = rnd0
     sess.escalations = level
     return sess

@@ -44,6 +44,7 @@ from repro.net import (
     ReliableTransport,
     TransportError,
     run_hub,
+    run_pair,
 )
 from repro.obs import (
     NULL_TRACER,
@@ -527,6 +528,27 @@ def test_hub_chaos_trace_acceptance(tmp_path):
 # ---------------------------------------------------------------------------
 # trace_report
 # ---------------------------------------------------------------------------
+
+
+def test_round_apply_outcomes_span_counts():
+    """Alice's one span per round over the outcome step: its counts of
+    recovered values, fake rejections and split-filtered slots add up to
+    the session's result."""
+    a, b = make_pair(2000, 60, np.random.default_rng(4))
+    cfg = PBSConfig(seed=1, n_override=63, t_override=3, g_override=4)
+    ta, tb = InMemoryDuplex.pair()
+    tr = Tracer()
+    alice, bob = AliceEndpoint(ta, tracer=tr), BobEndpoint(tb)
+    alice.submit(a, cfg=cfg, d_known=60)
+    bob.submit(b, cfg=cfg, d_known=60)
+    (res,) = run_pair(alice, bob).values()
+    assert res.success and res.decode_failures
+    spans = [e["args"] for e in tr.events() if e["name"] == "round.apply_outcomes"]
+    assert [s["round"] for s in spans] == list(range(1, res.rounds + 1))
+    assert {s["sessions"] for s in spans} == {1}
+    assert sum(s["recovered"] for s in spans) == len(res.diff)
+    assert sum(s["fake_rejections"] for s in spans) == res.fake_rejections
+    assert spans[0]["filtered_slots"] == 0 < sum(s["filtered_slots"] for s in spans)
 
 
 def test_trace_report_sections(tmp_path):
