@@ -12,10 +12,12 @@ per-session results and measured wire ledgers are byte-identical to
 ``core.pbs.reconcile`` (asserted in tests/test_net_endpoints.py and
 tests/test_recon_batch.py).
 
-``HubEndpoint`` (DESIGN.md §10) scales the serving side to N concurrent
-peers on channel-multiplexed transports: all peers' sessions fuse into one
-shared cohort pipeline, with per-peer round-barrier deadlines so a
-straggler or mid-protocol disconnect fails only its own peer.
+``HubEndpoint`` (DESIGN.md §10) is the one serving state machine, for N
+concurrent peers on channel-multiplexed transports: all peers' sessions
+fuse into one shared cohort pipeline, with per-peer round-barrier
+deadlines so a straggler or mid-protocol disconnect fails only its own
+peer.  ``BobEndpoint`` is that hub serving exactly one peer whose frames
+carry no ``MSG_MUX`` envelope — the pair.
 
 With ``continuous=True`` every endpoint also reconciles *divergent
 replicas continuously* (DESIGN.md §11): ``advance_epoch`` stages the next
@@ -33,8 +35,16 @@ barrier with zero store rebuilds; ``classify_error`` types every failure
 for ``PeerOutcome.error_kind``; and ``degrade=True`` endpoints escalate
 decode-budget-exhausted sessions instead of failing them.
 """
-from .endpoint import AliceEndpoint, BobEndpoint, run_pair, run_pair_epoch
-from .hub import HubEndpoint, PeerOutcome, run_hub, run_hub_epoch
+from .endpoint import AliceEndpoint
+from .hub import (
+    BobEndpoint,
+    HubEndpoint,
+    PeerOutcome,
+    run_hub,
+    run_hub_epoch,
+    run_pair,
+    run_pair_epoch,
+)
 from .resilience import ChaosTransport, FaultPlan, PeerDeadline, classify_error
 from .transport import (
     FrameStream,

@@ -1,19 +1,20 @@
-"""Alice/Bob PBS endpoints: the wire-separated halves of the protocol.
+"""The initiating PBS endpoint and the round helpers both wire sides share.
 
-Each endpoint owns exactly one side's data and device pipeline:
+``AliceEndpoint`` holds the A sets, runs phase 0 (ToW sketch out, d_hat
+numerator back), encodes her per-unit BCH sketches each round through the
+single-side cohort executor (``recon.engine.encode_side`` over her
+device-resident ``SessionBatch(sides=("a",))`` stores), applies the shared
+``core.pbs.apply_round_outcomes`` to the serving side's reply frames, and
+ships the checksum verdicts back as outcome frames.
 
-* ``AliceEndpoint`` holds the A sets, runs phase 0 (ToW sketch out, d_hat
-  numerator back), encodes her per-unit BCH sketches each round through the
-  single-side cohort executor (``recon.engine.encode_side`` over her
-  device-resident ``SessionBatch(sides=("a",))`` stores), applies the
-  shared ``core.pbs.apply_round_outcomes`` to Bob's reply frames, and ships
-  the checksum verdicts back as outcome frames.
-* ``BobEndpoint`` mirrors the session/unit state machine from the frames
-  alone: his own decode failures drive ``queue_split`` exactly like
-  Alice's, and her outcome frames supply the checksum-settled flags he
-  cannot compute (he never sees A).  His side batches the same way —
-  encode his sketches per cohort, XOR with the frame-decoded sketches,
-  ``bch_decode_batched`` for every unit of a cohort in one call.
+The serving side is one state machine, ``repro.net.hub.HubEndpoint``; the
+pair's ``BobEndpoint`` is that hub holding one peer whose frames carry no
+``MSG_MUX`` envelope.  It mirrors the session/unit state machine from the
+frames alone — its own decode failures drive ``queue_split`` exactly like
+Alice's, and her outcome frames supply the checksum-settled flags it cannot
+compute (it never sees A) — through the serving helpers defined here
+(``serve_phase0``, ``serve_epoch_frame``, ``serve_tree_frame``,
+``decode_side_b_round``, ``verify_ack_entries``).
 
 Byte ledgers are *measured*: every ``bytes_per_round`` entry an endpoint
 reports is derived from the frames that crossed the transport (via the
@@ -24,7 +25,6 @@ happens to equal ``core.pbs.reconcile``'s ledger exactly.
 """
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass
 
 import numpy as np
@@ -44,7 +44,6 @@ from repro.core.pbs import (
     parity_extension_t,
     plan_from_d_known,
     plan_from_estimate,
-    queue_split,
 )
 from repro.core.tow import (
     ESTIMATE_LIMIT_FRAC,
@@ -109,9 +108,9 @@ def encode_round_rows(
     tracer=None,
 ) -> dict[int, _SessionRows]:
     """Dispatch every cohort's single-side executor, then collect per-session
-    row slices (async dispatch overlaps cohorts).  Shared by the pair
-    endpoints and the multi-peer hub — the hub's ``plans`` span all peers'
-    sessions, so the two launches per cohort are fused across peers.
+    row slices (async dispatch overlaps cohorts).  Shared by Alice and the
+    hub — the hub's ``plans`` span all peers' sessions, so the two launches
+    per cohort are fused across peers.
 
     ``launches`` (if given) is bumped at the dispatch site — one
     ``encode_side`` call is one bin-kernel launch plus one sketch matmul —
@@ -157,9 +156,9 @@ def encode_round_rows_ext(
     Per cohort the syndrome matmul covers only columns
     [t_{level-1}·m, t_level·m) of the (n, t_level) code — the
     ``MSG_PARITY`` payload.  Cohorts whose t-ladder cannot grow at this
-    level (the (n-1)//2 code cap) are skipped.  Shared by the pair
-    endpoints and the multi-peer hub, which passes plans spanning all
-    peers so the two launches per cohort stay fused across peers.
+    level (the (n-1)//2 code cap) are skipped.  Shared by Alice and the
+    hub, which passes plans spanning all peers so the two launches per
+    cohort stay fused across peers.
 
     Returns sid -> (inc (U, t1-t0) int array, t0, t1).
     """
@@ -209,8 +208,6 @@ def serve_phase0(payload: bytes, set_b, cfg: PBSConfig,
     when the planned d̂ leaves the PBS operating regime for the pair's
     size (``limit_frac=None`` disables — the legacy burn-the-budget
     behavior); the tree front end (§15) is the route for such pairs.
-    Shared by ``BobEndpoint`` and the multi-peer hub so the two serving
-    paths cannot drift.
     """
     set_size_a, sk_a = wf.decode_tow_sketch(payload)
     if len(sk_a) != cfg.ell:
@@ -247,8 +244,6 @@ def serve_tree_frame(payload: bytes, walk: dict, stream, tally: dict,
     and advance the frontier by the shared deterministic split rule.
     Accumulates ``TREE_LEAF`` ranges into ``walk["leaves"]`` and the framed
     exchange bytes into both ``tally["tree"]`` and ``walk["bytes"]``.
-    Shared by ``BobEndpoint`` and the multi-peer hub so the two serving
-    paths cannot drift.
     """
     elems, tcfg, frontier = walk["elems"], walk["tcfg"], walk["frontier"]
     level, ell, cnt_a, cs_a, sk_a = wf.decode_tree_digest(payload)
@@ -279,16 +274,19 @@ def serve_tree_frame(payload: bytes, walk: dict, stream, tally: dict,
         stream.send(reply)
         tally["tree"] += len(reply)
         walk["bytes"] += len(reply)
-        li = 0
-        for (lo, hi), v in zip(frontier, verdicts):
-            if v == wf.TREE_LEAF:
-                walk["leaves"].append(
-                    TreeLeaf(lo=lo, hi=hi, d_plan=int(leaf_ds[li]))
-                )
-                li += 1
+        walk["leaves"] += _leaf_ranges(frontier, verdicts, leaf_ds)
         walk["frontier"] = split_ranges(frontier, verdicts)
         walk["level"] = level + 1
     return not walk["frontier"]
+
+
+def _leaf_ranges(frontier, verdicts, leaf_ds) -> list[TreeLeaf]:
+    """One tree level's ``TREE_LEAF`` ranges, each with its planned d."""
+    spans = [r for r, v in zip(frontier, verdicts) if v == wf.TREE_LEAF]
+    return [
+        TreeLeaf(lo=lo, hi=hi, d_plan=int(d))
+        for (lo, hi), d in zip(spans, leaf_ds)
+    ]
 
 
 def serve_epoch_frame(payload: bytes, expected_epoch: int, pending: dict,
@@ -305,8 +303,8 @@ def serve_epoch_frame(payload: bytes, expected_epoch: int, pending: dict,
     recording the plan in ``plans``.  A bare epoch-open is only legal
     when nothing re-estimates, and is answered bare.  Ledger mirrors
     ``MSG_MUX``: inner phase-0 bits to the estimator tally, envelope
-    bytes to the epoch tally.  Shared by ``BobEndpoint`` and the hub so
-    the two serving paths cannot drift.
+    bytes to the epoch tally.  The tallies and the plan are recorded before
+    the send: a reply whose send raises may still have arrived.
     """
     e, ity, ipayload = wf.decode_epoch(payload)
     if e != expected_epoch:
@@ -319,8 +317,8 @@ def serve_epoch_frame(payload: bytes, expected_epoch: int, pending: dict,
         if est:
             raise WireError("bare epoch-open with estimator sessions pending")
         reply = wf.encode_epoch(e)
-        stream.send(reply)
         tally["epoch"] += _framed_len(payload) + len(reply)
+        stream.send(reply)
         return True
     if ity != wf.MSG_TOW_SKETCH:
         raise WireError(f"unexpected epoch inner frame type 0x{ity:02x}")
@@ -332,13 +330,13 @@ def serve_epoch_frame(payload: bytes, expected_epoch: int, pending: dict,
         ipayload, elems, cfg_of(sid), limit_frac
     )
     reply = wf.encode_epoch(e, inner_reply)
-    stream.send(reply)
     tally["estimator"] += est_bytes
     tally["epoch"] += (
         _framed_len(payload) - framed_len(len(ipayload))
         + len(reply) - len(inner_reply)
     )
     plans[sid] = plan
+    stream.send(reply)
     return len(est) == 1
 
 
@@ -358,9 +356,8 @@ def decode_side_b_round(
     (an evicted hub peer) keep zero rows — padding decodes trivially-ok and
     they are skipped in the result.  Returns (results: sid -> (ok, units),
     ctx: sid -> (sess, active, ok, bin_seed)) — ``ctx`` is what the
-    outcome-frame mirror needs.  Shared by ``BobEndpoint`` and the hub; in
-    the hub's case ``plans`` span every peer, so the decode launch is fused
-    across peers.
+    outcome-frame mirror needs.  ``plans`` span every peer of the hub, so
+    the decode launch is fused across peers.
     """
     inflight = []
     for plan in plans:
@@ -423,7 +420,7 @@ def decode_side_b_round(
 def verify_ack_entries(payload: bytes, sessions):
     """Decode a VERIFY frame and compute the serving side's verdicts:
     the peer claims success AND c(A △ D̂) equals our c(B).  Returns
-    (ack frame, flags).  Shared by ``BobEndpoint`` and the hub."""
+    (ack frame, flags)."""
     entries = wf.decode_verify(payload, len(sessions))
     flags = [
         bool(success) and csum_eff == checksum(sess.state.b)
@@ -465,10 +462,44 @@ def stream_wire_stats(
     }
 
 
-class _Endpoint:
-    """Shared plumbing: submissions, cohort batch, side encode, tallies."""
+def roll_back(tally: dict, marks: dict) -> None:
+    """Move a partial attempt's bytes — each category's excess over its
+    mark at the last barrier — to the resume tally: the attempt re-runs,
+    so the Formula-(1) categories count it once (DESIGN.md §13)."""
+    for cat, mark in marks.items():
+        tally["resume"] += tally[cat] - mark
+        tally[cat] = mark
 
-    side: str
+
+def reattach(old: FrameStream, transport: Transport,
+             carry: dict) -> tuple[FrameStream, dict]:
+    """A resumed stream over ``transport``: the old stream's framing and
+    frame counters carry over, and the returned ``carry`` adds the old
+    transport's totals so ``stream_wire_stats`` stays cumulative."""
+    t = old.transport
+    carry = {
+        "transport_bytes_out": t.bytes_out + carry.get("transport_bytes_out", 0),
+        "transport_bytes_in": t.bytes_in + carry.get("transport_bytes_in", 0),
+        "retransmits": getattr(t, "retransmits", 0) + carry.get("retransmits", 0),
+    }
+    stream = FrameStream(transport, channel=old.channel)
+    for k in ("frames_out", "frames_in", "bytes_out", "bytes_in",
+              "mux_bytes_out", "mux_bytes_in"):
+        setattr(stream, k, getattr(old, k))
+    return stream, carry
+
+
+class AliceEndpoint:
+    """The initiating endpoint; learns A △ B for every submitted session.
+
+    Her serving peer is a ``HubEndpoint``: with ``channel=`` one of its
+    many multiplexed peers, without it the one unwrapped peer of a
+    ``BobEndpoint`` (DESIGN.md §9–§10).  ``estimate_limit`` is enforced
+    where d̂ is planned, on the serving side; it is accepted here so both
+    ends of a pair take the same options.
+    """
+
+    side = "a"
 
     def __init__(
         self,
@@ -492,20 +523,17 @@ class _Endpoint:
         self.tracer = tracer if tracer is not None else current_tracer()
         self._continuous = continuous
         self._degrade = degrade
-        # phase-0 operating-regime guard (§15): planned d̂ beyond this
-        # fraction of |A| + |B| raises EstimateOutOfRange; None disables
-        self._estimate_limit = estimate_limit
         self._sessions: list[ReconSession | None] = []
         self._est_queue: list[int] = []     # sids awaiting phase 0, in order
+        self._pending: dict[int, tuple] = {}   # sid -> (a, cfg) for phase 0
         self._batch: SessionBatch | None = None
         self._tally = {
             "estimator": 0, "protocol": 0, "verify": 0, "epoch": 0,
             "resume": 0, "tree": 0,
         }
         # tree front end (§15): staged (elems, cfg, tcfg) awaiting the walk,
-        # the serving side's in-flight walk state, and the outcome summary
+        # and the outcome summary
         self._tree: tuple | None = None
-        self._tree_walk: dict | None = None
         self.tree_depth = 0
         self.tree_leaves: int | None = None
         self._d_known: dict[int, int | None] = {}
@@ -515,38 +543,45 @@ class _Endpoint:
         self.sessions_degraded = 0          # degradation-ladder escalations
         self.parity_extensions = 0          # rateless ladder levels applied
         self.verified: list[bool] | None = None
+        # resumption state (DESIGN.md §13): the last completed local round
+        # barrier, the rolling transcript digests at that barrier and the
+        # one before, the framed outcome bytes of the last barrier (replayed
+        # when the hub missed them), and the per-category tally marks the
+        # partial-attempt rollback restores on resume.
+        self._rnd = 0
+        self._digest = wf.transcript_digest0(0)
+        self._digest_prev = self._digest
+        self._last_outcome: bytes | None = None
+        self._marks = {"protocol": 0, "verify": 0, "epoch": 0, "estimator": 0}
+        self.resumes = 0
 
     # -- submission ------------------------------------------------------
 
-    def _submit(self, elems, cfg: PBSConfig | None, d_known: int | None):
+    def submit(self, set_a, cfg: PBSConfig | None = None, d_known: int | None = None) -> int:
+        """Enqueue one session (this endpoint holds ``set_a``); the peer
+        must ``submit`` the matching ``set_b`` with the same cfg/d_known in
+        the same order — session identity is positional, like the paper's
+        out-of-band-agreed hash functions."""
         with self.tracer.span("endpoint.submit", side=self.side,
-                              keys=len(elems)):
+                              keys=len(set_a)):
             cfg = cfg or PBSConfig()
-            elems = np.unique(np.asarray(elems, dtype=np.uint32))
+            elems = np.unique(np.asarray(set_a, dtype=np.uint32))
             sid = len(self._sessions)
             self._d_known[sid] = d_known
             if d_known is not None:
-                self._install(sid, elems, plan_from_d_known(cfg, d_known),
-                              append=True)
+                self._sessions.append(self._new_session(
+                    sid, elems, plan_from_d_known(cfg, d_known)
+                ))
             else:
                 self._sessions.append(None)
                 self._est_queue.append(sid)
-                self._pending_store(sid, elems, cfg)
+                self._pending[sid] = (elems, cfg)
             return sid
 
-    def _install(self, sid, elems, plan, *, append: bool):
-        a, b = (elems, _EMPTY) if self.side == "a" else (_EMPTY, elems)
+    def _new_session(self, sid, elems, plan) -> ReconSession:
         with self.tracer.span("session.state", side=self.side, keys=len(elems)):
-            state = new_session_state(a, b, plan)
-        sess = ReconSession(sid=sid, plan=plan, state=state)
-        if append:
-            self._sessions.append(sess)
-        else:
-            self._sessions[sid] = sess
-        return sess
-
-    def _pending_store(self, sid, elems, cfg):
-        raise NotImplementedError
+            state = new_session_state(elems, _EMPTY, plan)
+        return ReconSession(sid=sid, plan=plan, state=state)
 
     # -- tree front end (DESIGN.md §15) ----------------------------------
 
@@ -557,7 +592,7 @@ class _Endpoint:
         ordinary known-d session appended after all regular submits — so
         the peer must ``submit_tree`` its matching side with the same
         ``cfg``/``tree`` (positional contract, like ``submit``)."""
-        if self._tree is not None or self._tree_walk is not None:
+        if self._tree is not None:
             raise RuntimeError("a tree phase is already staged")
         if self._batch is not None:
             raise RuntimeError("tree staging after the session batch formed")
@@ -567,23 +602,10 @@ class _Endpoint:
             tree or TreeConfig(),
         )
 
-    def _collect_leaves(self, frontier, verdicts, leaf_ds, leaves) -> None:
-        li = 0
-        for (lo, hi), v in zip(frontier, verdicts):
-            if v == wf.TREE_LEAF:
-                leaves.append(TreeLeaf(lo=lo, hi=hi, d_plan=int(leaf_ds[li])))
-                li += 1
-
-    def _install_tree_leaves(self, elems, cfg, leaves, depth: int) -> None:
-        self.tree_depth = depth
-        self.tree_leaves = len(leaves)
-        for sub, leaf in zip(leaf_slices(elems, leaves), leaves):
-            self._submit(sub, cfg, d_known=leaf.d_plan)
-
     # -- round machinery -------------------------------------------------
 
     def _ensure_batch(self) -> SessionBatch:
-        if self._tree is not None or self._tree_walk is not None:
+        if self._tree is not None:
             raise WireError("round traffic before the tree phase completed")
         if self._est_queue:
             raise WireError("round traffic before phase 0 completed")
@@ -597,18 +619,20 @@ class _Endpoint:
     # -- continuous sync (DESIGN.md §11) ---------------------------------
 
     def advance_epoch(self, mutations: dict | None = None, *,
-                      d_known: dict | None = None) -> int:
-        """Stage the next epoch's sets: the initiating side folds its
-        learned diff (replica convergence), then this side's local churn
-        from ``mutations`` (sid -> (added, removed)) applies.  ``d_known``
+                      d_known: dict | None = None,
+                      fold_diff: bool = True) -> int:
+        """Stage the next epoch's sets: with ``fold_diff`` (the default)
+        each session first folds its learned diff into A — replica
+        convergence: A ← A △ D̂ = B — then this side's local churn from
+        ``mutations`` (sid -> (added, removed)) applies.  ``d_known``
         (sid -> int | None) *rebinds* a session's d convention from this
         epoch on — an int pins d for this and later epochs, ``None``
         returns the session to re-running the d̂ handshake over the wire;
         sessions not mentioned keep their current convention (initially
         the submit-time one).  The epoch itself runs on the next
-        ``run_epoch``/``serve_epoch``, which patches the resident stores
-        with the net delta in place.  Requires ``continuous=True`` (stores
-        packed with mutation lanes).
+        ``run_epoch``, which patches the resident stores with the net
+        delta in place.  Requires ``continuous=True`` (stores packed with
+        mutation lanes).
         """
         if not self._continuous:
             raise RuntimeError("advance_epoch needs continuous=True")
@@ -627,24 +651,13 @@ class _Endpoint:
         pending: dict[int, tuple] = {}
         for s in self._sessions:
             added, removed = muts.get(s.sid, (_EMPTY, _EMPTY))
+            st = s.state
+            base = effective_set(st.a, st.diff) if fold_diff else st.a
             pending[s.sid] = (
-                apply_churn(self._epoch_base(s), added, removed),
-                self._d_known[s.sid],
+                apply_churn(base, added, removed), self._d_known[s.sid],
             )
         self._epoch_pending = pending
         return self._epoch
-
-    def _epoch_base(self, sess: ReconSession) -> np.ndarray:
-        """This side's set going into the next epoch, before local churn."""
-        raise NotImplementedError
-
-    def _encode_round(self, plans: list[CohortRoundPlan]) -> dict[int, _SessionRows]:
-        return encode_round_rows(plans, self.side, self._interpret,
-                                 tracer=self.tracer)
-
-    @staticmethod
-    def _schema(per: dict[int, _SessionRows], live: list[int]):
-        return round_schema(per, live)
 
     def _expect(self, msg_type: int) -> bytes:
         got, payload = self._stream.recv()
@@ -678,80 +691,23 @@ class _Endpoint:
         self.recorder.publish(
             "wire", stream_wire_stats(self._stream, self._tally, self._carry)
         )
-        self.recorder.set("endpoint.resumes", getattr(self, "resumes", 0))
+        self.recorder.set("endpoint.resumes", self.resumes)
         self.recorder.set("endpoint.sessions_degraded", self.sessions_degraded)
         self.recorder.set("endpoint.parity_extensions", self.parity_extensions)
         return self.recorder.view("wire")
-
-
-class AliceEndpoint(_Endpoint):
-    """The initiating endpoint; learns A △ B for every submitted session."""
-
-    side = "a"
-
-    def __init__(
-        self,
-        transport: Transport,
-        *,
-        interpret: bool | None = None,
-        channel: int | None = None,
-        continuous: bool = False,
-        degrade: bool = False,
-        estimate_limit: float | None = ESTIMATE_LIMIT_FRAC,
-        recorder: Recorder | None = None,
-        tracer=None,
-    ):
-        super().__init__(transport, interpret=interpret, channel=channel,
-                         continuous=continuous, degrade=degrade,
-                         estimate_limit=estimate_limit,
-                         recorder=recorder, tracer=tracer)
-        self._pending: dict[int, tuple] = {}   # sid -> (a, cfg)
-        self._fold_diff = True
-        # resumption state (DESIGN.md §13): the last completed local round
-        # barrier, the rolling transcript digests at that barrier and the
-        # one before, the framed outcome bytes of the last barrier (replayed
-        # when the hub missed them), and the per-category tally marks the
-        # partial-round rollback restores on resume.
-        self._rnd = 0
-        self._digest = wf.transcript_digest0(0)
-        self._digest_prev = self._digest
-        self._last_outcome: bytes | None = None
-        self._marks = {"protocol": 0, "verify": 0}
-        self.resumes = 0
-
-    def _pending_store(self, sid, elems, cfg):
-        self._pending[sid] = (elems, cfg)
-
-    def submit(self, set_a, cfg: PBSConfig | None = None, d_known: int | None = None) -> int:
-        """Enqueue one session (this endpoint holds ``set_a``); the peer
-        must ``submit`` the matching ``set_b`` with the same cfg/d_known in
-        the same order — session identity is positional, like the paper's
-        out-of-band-agreed hash functions."""
-        return self._submit(set_a, cfg, d_known)
-
-    def advance_epoch(self, mutations: dict | None = None, *,
-                      d_known: dict | None = None,
-                      fold_diff: bool = True) -> int:
-        """Stage the next epoch (see ``_Endpoint.advance_epoch``); with
-        ``fold_diff`` (the default) each session first folds its learned
-        diff into A — replica convergence: A ← A △ D̂ = B — before this
-        side's local churn applies."""
-        self._fold_diff = fold_diff
-        return super().advance_epoch(mutations, d_known=d_known)
-
-    def _epoch_base(self, sess: ReconSession) -> np.ndarray:
-        st = sess.state
-        return effective_set(st.a, st.diff) if self._fold_diff else st.a
 
     def run_epoch(self) -> dict[int, ReconcileResult]:
         """Drive one staged epoch over the wire: the ``MSG_EPOCH``
         handshake (epoch id + d̂ re-estimation through the phase-0 codecs),
         an in-place delta patch of the resident stores, then the same
         round/verify machinery as ``run`` — per-epoch results are
-        byte-identical to a fresh session over the epoch's sets."""
+        byte-identical to a fresh session over the epoch's sets.  The
+        epoch stays staged until its handshake completes, so a resume
+        after a failure mid-handshake runs it again (``resume_run``)."""
         if self._epoch_pending is None:
             raise RuntimeError("no epoch staged: call advance_epoch first")
-        pending, self._epoch_pending = self._epoch_pending, None
+        pending = self._epoch_pending
+        self._marks = {k: self._tally[k] for k in self._marks}
         e = self._epoch
         self.tracer.instant("epoch.open", epoch=e)
         batch = self._ensure_batch()
@@ -809,6 +765,7 @@ class AliceEndpoint(_Endpoint):
             sess = self._sessions[sid]
             plan = plans.get(sid) or plan_from_d_known(sess.plan.cfg, dk)
             advance_session(batch, sess, plan, new_a=elems, rnd0=0)
+        self._epoch_pending = None
         self._reset_rounds()
         return self._run_rounds()
 
@@ -860,10 +817,13 @@ class AliceEndpoint(_Endpoint):
                         f"tree verdict covers {len(verdicts)} ranges, "
                         f"frontier has {len(frontier)}"
                     )
-                self._collect_leaves(frontier, verdicts, leaf_ds, leaves)
+                leaves += _leaf_ranges(frontier, verdicts, leaf_ds)
                 frontier = split_ranges(frontier, verdicts)
             level += 1
-        self._install_tree_leaves(elems, cfg, leaves, max(level - 1, 0))
+        self.tree_depth = max(level - 1, 0)
+        self.tree_leaves = len(leaves)
+        for sub, leaf in zip(leaf_slices(elems, leaves), leaves):
+            self.submit(sub, cfg, d_known=leaf.d_plan)
 
     def _reset_rounds(self) -> None:
         """Re-arm the round loop and resumption state for a fresh epoch."""
@@ -882,9 +842,10 @@ class AliceEndpoint(_Endpoint):
             if not plans:
                 break
             with tracer.span("round.encode", round=rnd, cohorts=len(plans)):
-                per = self._encode_round(plans)
+                per = encode_round_rows(plans, self.side, self._interpret,
+                                        tracer=self.tracer)
             live = sorted(per)
-            schema = self._schema(per, live)
+            schema = round_schema(per, live)
 
             sk_frame = wf.encode_round_sketches(
                 rnd, [(per[sid].sk, per[sid].plan.store.m) for sid in live]
@@ -1012,7 +973,7 @@ class AliceEndpoint(_Endpoint):
 
         While any rateless session has units whose BCH decode failed and
         its cohort's t can still grow, ship only the incremental syndrome
-        columns for the failing units and fold Bob's extension replies
+        columns for the failing units and fold the serving side's extension replies
         into ``ent_of`` in place — the merged entries drive the single
         ``apply_round_outcomes`` downstream, so settled units are never
         re-sent and split seeds still derive from this round.  Returns
@@ -1112,27 +1073,10 @@ class AliceEndpoint(_Endpoint):
             self._resume(transport)
 
     def _resume(self, transport: Transport) -> None:
-        for cat, mark in self._marks.items():
-            spill = self._tally[cat] - mark
-            if spill:
-                self._tally[cat] = mark
-                self._tally["resume"] += spill
-        old = self._stream
-        t_old = old.transport
-        self._carry = {
-            "transport_bytes_out": t_old.bytes_out
-            + self._carry.get("transport_bytes_out", 0),
-            "transport_bytes_in": t_old.bytes_in
-            + self._carry.get("transport_bytes_in", 0),
-            "retransmits": getattr(t_old, "retransmits", 0)
-            + self._carry.get("retransmits", 0),
-        }
-        stream = FrameStream(transport, channel=old.channel)
-        stream.frames_out, stream.frames_in = old.frames_out, old.frames_in
-        stream.bytes_out, stream.bytes_in = old.bytes_out, old.bytes_in
-        stream.mux_bytes_out = old.mux_bytes_out
-        stream.mux_bytes_in = old.mux_bytes_in
-        self._stream = stream
+        roll_back(self._tally, self._marks)
+        self._stream, self._carry = reattach(self._stream, transport,
+                                             self._carry)
+        stream = self._stream
 
         f = wf.encode_resume(
             stream.channel, self._epoch, self._rnd,
@@ -1165,7 +1109,10 @@ class AliceEndpoint(_Endpoint):
     def resume_run(self) -> dict[int, ReconcileResult]:
         """Continue a resumed protocol from the re-aligned barrier to
         completion — the round loop picks up at ``self._rnd + 1`` over the
-        intact session states and cohort stores."""
+        intact session states and cohort stores, after the epoch handshake
+        when a failure interrupted it."""
+        if self._epoch_pending is not None:
+            return self.run_epoch()
         return self._run_rounds()
 
     def _phase0(self):
@@ -1194,7 +1141,7 @@ class AliceEndpoint(_Endpoint):
                     f"sid {sid}: estimator frames measure {est_frames} B, "
                     f"accounted {plan.est_bytes} B"
                 )
-            self._install(sid, a, plan, append=False)
+            self._sessions[sid] = self._new_session(sid, a, plan)
         self._est_queue.clear()
 
     def _verify(self):
@@ -1212,393 +1159,6 @@ class AliceEndpoint(_Endpoint):
         self.verified = wf.decode_verify_ack(payload, len(self._sessions))
 
 
-class BobEndpoint(_Endpoint):
-    """The serving endpoint; holds the B sets and answers frames until the
-    final verification exchange, mirroring every session's unit queue."""
-
-    side = "b"
-
-    def __init__(
-        self,
-        transport: Transport,
-        *,
-        interpret: bool | None = None,
-        channel: int | None = None,
-        continuous: bool = False,
-        degrade: bool = False,
-        estimate_limit: float | None = ESTIMATE_LIMIT_FRAC,
-        recorder: Recorder | None = None,
-        tracer=None,
-    ):
-        super().__init__(transport, interpret=interpret, channel=channel,
-                         continuous=continuous, degrade=degrade,
-                         estimate_limit=estimate_limit,
-                         recorder=recorder, tracer=tracer)
-        self._pending: dict[int, tuple] = {}   # sid -> (b, cfg)
-        self._rnd = 0                          # rounds whose sketches arrived
-        self._ctx = None                       # current round's (live, per-sid)
-        self._epoch_plans: dict[int, object] = {}
-
-    def _pending_store(self, sid, elems, cfg):
-        self._pending[sid] = (elems, cfg)
-
-    def _epoch_base(self, sess: ReconSession) -> np.ndarray:
-        return sess.state.b
-
-    def submit(self, set_b, cfg: PBSConfig | None = None, d_known: int | None = None) -> int:
-        """Enqueue this endpoint's side of the next session (positional
-        pairing with the peer's ``submit`` order)."""
-        return self._submit(set_b, cfg, d_known)
-
-    def serve_epoch(self) -> None:
-        """Serve one staged epoch: the peer's ``MSG_EPOCH`` handshake
-        (validated against the locally staged epoch id), the in-place
-        store delta patch, then frames until the epoch's verification
-        exchange completes."""
-        if self._epoch_pending is None:
-            raise RuntimeError("no epoch staged: call advance_epoch first")
-        self.serve()
-
-    def serve(self) -> None:
-        """Answer frames until the verification exchange completes."""
-        while True:
-            msg_type, payload = self._stream.recv()
-            if msg_type == wf.MSG_TREE:
-                self._handle_tree(payload)
-            elif msg_type == wf.MSG_TOW_SKETCH:
-                self._handle_tow(payload)
-            elif msg_type == wf.MSG_EPOCH:
-                self._handle_epoch(payload)
-            elif msg_type == wf.MSG_ROUND_SKETCHES:
-                self._handle_sketches(payload)
-            elif msg_type == wf.MSG_PARITY:
-                self._handle_parity(payload)
-            elif msg_type == wf.MSG_ROUND_OUTCOME:
-                self._handle_outcome(payload)
-            elif msg_type == wf.MSG_VERIFY:
-                self._handle_verify(payload)
-                return
-            else:
-                raise WireError(f"unexpected message type 0x{msg_type:02x}")
-
-    def _handle_tree(self, payload: bytes) -> None:
-        """Answer one level of the peer's tree walk (§15) through the
-        shared ``serve_tree_frame``; when the deterministic split rule
-        empties the frontier, install the accumulated leaf sessions."""
-        if self._tree_walk is None:
-            if self._tree is None:
-                raise WireError("tree frame with no tree phase staged")
-            elems, cfg, tcfg = self._tree
-            self._tree = None
-            self._tree_walk = tree_walk_state(elems, cfg, tcfg)
-        w = self._tree_walk
-        if serve_tree_frame(payload, w, self._stream, self._tally,
-                            self.tracer, self._interpret):
-            self._tree_walk = None
-            self._install_tree_leaves(
-                w["elems"], w["cfg"], w["leaves"], w["level"] - 1
-            )
-
-    def _handle_epoch(self, payload: bytes) -> None:
-        """One step of the peer's epoch handshake (the shared
-        ``serve_epoch_frame`` state machine); once every staged session
-        has its plan, fold the epoch in: delta-patch the resident store
-        and reset the round state machine."""
-        if self._epoch_pending is None:
-            raise WireError("epoch frame with no epoch advance staged")
-        done = serve_epoch_frame(
-            payload, self._epoch, self._epoch_pending, self._epoch_plans,
-            lambda sid: self._sessions[sid].plan.cfg,
-            self._stream, self._tally, self._estimate_limit,
-        )
-        if done:
-            self._install_epoch()
-
-    def _install_epoch(self) -> None:
-        batch = self._ensure_batch()
-        pending, self._epoch_pending = self._epoch_pending, None
-        for sid in sorted(pending):
-            elems, dk = pending[sid]
-            sess = self._sessions[sid]
-            plan = self._epoch_plans.get(sid) or plan_from_d_known(
-                sess.plan.cfg, dk
-            )
-            advance_session(batch, sess, plan, new_b=elems, rnd0=0)
-        self._epoch_plans = {}
-        self._rnd = 0
-        self._ctx = None
-
-    def _handle_tow(self, payload: bytes) -> None:
-        if not self._est_queue:
-            raise WireError("ToW sketch frame with no estimator session pending")
-        sid = self._est_queue.pop(0)
-        b, cfg = self._pending.pop(sid)
-        reply, plan, est_bytes = serve_phase0(
-            payload, b, cfg, self._estimate_limit
-        )
-        self._stream.send(reply)
-        self._tally["estimator"] += est_bytes
-        self._install(sid, b, plan, append=False)
-
-    def _handle_sketches(self, payload: bytes) -> None:
-        if self._ctx is not None:
-            raise WireError("sketch frame while a round outcome is pending")
-        if self._epoch_pending is not None:
-            raise WireError("round traffic before the staged epoch handshake")
-        batch = self._ensure_batch()
-        rnd = self._rnd + 1
-        plans = batch.plan_round(rnd)
-        with self.tracer.span("round.encode", round=rnd,
-                              cohorts=len(plans)):
-            per = self._encode_round(plans)
-        live = sorted(per)
-        schema = self._schema(per, live)
-        got_rnd, blocks = wf.decode_round_sketches(payload, schema)
-        if got_rnd != rnd:
-            raise WireError(f"sketch frame for round {got_rnd}, expected {rnd}")
-        self._rnd = rnd
-        self._tally["protocol"] += _framed_len(payload)
-
-        # per cohort: place each session's frame sketches at its row slice,
-        # XOR with our device-resident side, decode every unit at once
-        # (padding rows carry zero sketches on both sides: trivially ok)
-        with self.tracer.span("round.decode", round=rnd,
-                              sessions=len(live)):
-            results, ctx = decode_side_b_round(
-                plans, per, dict(zip(live, blocks)), tracer=self.tracer
-            )
-        reply = wf.encode_round_reply(rnd, [results[sid] for sid in live], schema)
-        self._stream.send(reply)
-        self._tally["protocol"] += len(reply)
-        # rateless ladder state (§16): the failing slots of every rateless
-        # session, plus everything a MSG_PARITY extension needs to re-decode
-        # this round's bitmaps at a wider t — cached frame sketches (the
-        # prefix), our row slices, and the cohort plans.
-        fail: dict[int, list[int]] = {}
-        for sid in live:
-            sess, active, ok, _ = ctx[sid]
-            if not sess.plan.cfg.rateless:
-                continue
-            bad = [s for s in range(len(active)) if not ok[s]]
-            if bad:
-                fail[sid] = bad
-        self._ctx = {
-            "live": live, "ctx": ctx, "per": per, "plans": plans,
-            "sk_a": dict(zip(live, blocks)), "fail": fail, "level": 0,
-            "acc": {},
-        }
-
-    def _handle_parity(self, payload: bytes) -> None:
-        """Serve one ``MSG_PARITY`` rateless extension (DESIGN.md §16).
-
-        XOR Alice's incremental syndrome columns with our own side's, grow
-        each failing unit's cached round-diff prefix, re-decode per cohort
-        in one batched launch at the extended t, and reply with the
-        extension outcomes through the ordinary round-reply codec.  The
-        round context's ``ok`` arrays are merged in place, so the outcome
-        frame (and any resume replay) sees the post-ladder verdicts.
-        """
-        c = self._ctx
-        if c is None:
-            raise WireError("parity frame with no round in flight")
-        fail = c["fail"]
-        level = c["level"] + 1
-        if level > MAX_PARITY_EXTENSIONS:
-            raise WireError(f"parity frame beyond the level-{level - 1} cap")
-        part_plans = [
-            plan for plan in c["plans"]
-            if any(sess.sid in fail for sess, *_ in plan.members)
-        ]
-        inc_of = encode_round_rows_ext(
-            part_plans, self.side, level, self._interpret,
-            tracer=self.tracer,
-        )
-        parts = [sid for sid in c["live"] if sid in fail and sid in inc_of]
-        if not parts:
-            raise WireError("unexpected parity frame: no extension pending")
-        schema = [
-            (len(fail[sid]), inc_of[sid][2] - inc_of[sid][1],
-             c["per"][sid].plan.store.m)
-            for sid in parts
-        ]
-        # reply schema before the merge loop mutates ``fail``: the ext
-        # reply covers every unit that was failing at this level, at t1
-        reply_schema = [
-            (len(fail[sid]), inc_of[sid][2], c["per"][sid].plan.store.m)
-            for sid in parts
-        ]
-        got_rnd, got_level, blocks = wf.decode_parity(payload, schema)
-        if got_rnd != self._rnd:
-            raise WireError(
-                f"parity frame for round {got_rnd}, expected {self._rnd}"
-            )
-        if got_level != level:
-            raise WireError(
-                f"parity frame at level {got_level}, expected {level}"
-            )
-        self._tally["protocol"] += _framed_len(payload)
-
-        # grow each failing unit's accumulated diff syndromes: prefix
-        # (frame sketch ^ our sketch, cached at decode time) + increments
-        acc = c["acc"]
-        for sid, inc_a in zip(parts, blocks):
-            inc_b = inc_of[sid][0]
-            prefix_a = c["sk_a"][sid]
-            sk_b = c["per"][sid].sk
-            slot_acc = acc.setdefault(sid, {})
-            for i, slot in enumerate(fail[sid]):
-                prev = slot_acc.get(slot)
-                if prev is None:
-                    prev = np.asarray(prefix_a[slot], dtype=np.int64) ^ np.asarray(
-                        sk_b[slot], dtype=np.int64
-                    )
-                d = np.asarray(inc_a[i], dtype=np.int64) ^ np.asarray(
-                    inc_b[slot], dtype=np.int64
-                )
-                slot_acc[slot] = np.concatenate([prev, d])
-
-        # one batched decode per cohort: failing rows scattered into a
-        # padded buffer, settled rows stay zero (trivially ok, ignored)
-        entries: dict[int, tuple] = {}
-        for plan in part_plans:
-            n, t = plan.store.n, plan.store.t
-            t1 = parity_extension_t(t, level, n)
-            if t1 <= parity_extension_t(t, level - 1, n):
-                continue
-            u_pad = plan.arrays["row_map"].shape[0]
-            buf = np.zeros((u_pad, t1), dtype=np.int64)
-            hit = False
-            for sess, base, active, _ in plan.members:
-                if sess.sid not in parts:
-                    continue
-                for slot in fail[sess.sid]:
-                    buf[base + slot] = acc[sess.sid][slot]
-                    hit = True
-            if not hit:
-                continue
-            ok_p, pos_p, cnt_p = (
-                np.asarray(x) for x in readback(
-                    bch_decode_batched(
-                        jnp.asarray(buf, dtype=jnp.int32), n=n, t=t1
-                    ),
-                    "parity_decode", self.tracer,
-                )
-            )
-            for sess, base, active, _ in plan.members:
-                sid = sess.sid
-                if sid not in parts:
-                    continue
-                row = c["per"][sid]
-                ok_m = c["ctx"][sid][2]
-                ok_e, units, still = [], [], []
-                for slot in fail[sid]:
-                    if ok_p[base + slot]:
-                        k = int(cnt_p[base + slot])
-                        p = pos_p[base + slot, :k].astype(np.int64)
-                        units.append(
-                            ReplyUnit(
-                                positions=p,
-                                xors=row.xors[slot, p],
-                                csum=int(row.csum[slot]),
-                            )
-                        )
-                        ok_e.append(True)
-                        ok_m[slot] = True   # in-place: outcome/resume see it
-                    else:
-                        units.append(None)
-                        ok_e.append(False)
-                        still.append(slot)
-                entries[sid] = (ok_e, units)
-                if still:
-                    fail[sid] = still
-                else:
-                    del fail[sid]
-                self.parity_extensions += 1
-        c["level"] = level
-        reply = wf.encode_round_reply(
-            self._rnd, [entries[sid] for sid in parts], reply_schema
-        )
-        self._stream.send(reply)
-        self._tally["protocol"] += len(reply)
-
-    def _handle_outcome(self, payload: bytes) -> None:
-        if self._ctx is None:
-            raise WireError("outcome frame with no round in flight")
-        live, ctx = self._ctx["live"], self._ctx["ctx"]
-        self._ctx = None
-        rnd = self._rnd
-        got_rnd, done_lists = wf.decode_round_outcome(
-            payload, [len(ctx[sid][1]) for sid in live]
-        )
-        if got_rnd != rnd:
-            raise WireError(f"outcome frame for round {got_rnd}, expected {rnd}")
-        self._tally["protocol"] += _framed_len(payload)
-        for sid, done in zip(live, done_lists):
-            sess, active, ok, _ = ctx[sid]
-            rloc = rnd - sess.rnd0       # local protocol round
-            for slot, u in enumerate(active):
-                if not ok[slot]:
-                    # our decode failed: mirror Alice's 3-way split verbatim
-                    queue_split(sess.state, u, rloc, sess.plan.cfg.seed)
-                elif done[slot]:
-                    u.done = True
-            sess.state.rounds = rloc
-        self._degrade_after(rnd)
-
-    def _handle_verify(self, payload: bytes) -> None:
-        # Alice's A △ D̂ must sum to our B when she really learned A △ B
-        ack, flags = verify_ack_entries(payload, self._sessions)
-        self._tally["verify"] += _framed_len(payload)
-        self._stream.send(ack)
-        self._tally["verify"] += len(ack)
-        self.verified = flags
-
-
 def _framed_len(payload: bytes) -> int:
     """Exact framed size of a received payload (envelope + type + body)."""
     return framed_len(len(payload))
-
-
-def _drive_pair(alice, bob, alice_call, bob_call) -> dict[int, ReconcileResult]:
-    """Run one Alice step against one Bob step on a worker thread, with
-    Bob's root-cause exception taking precedence (see ``run_pair``)."""
-    err: list[BaseException] = []
-
-    def _serve():
-        try:
-            bob_call()
-        except BaseException as e:  # noqa: BLE001 - relayed to the caller
-            err.append(e)
-            bob._stream.transport.close()  # unblock the peer's recv
-
-    th = threading.Thread(target=_serve, name="bob-endpoint", daemon=True)
-    th.start()
-    try:
-        results = alice_call()
-    except BaseException:
-        th.join(timeout=5.0)
-        if err:
-            raise err[0]  # Bob's failure is the root cause, not Alice's
-        raise
-    th.join(timeout=60.0)
-    if err:
-        raise err[0]
-    return results
-
-
-def run_pair(alice: AliceEndpoint, bob: BobEndpoint) -> dict[int, ReconcileResult]:
-    """Drive a connected endpoint pair to completion: Bob serves on a
-    worker thread, Alice runs on the caller's; Bob's exceptions re-raise.
-
-    A failing serve() closes Bob's transport so a blocked Alice fails fast
-    instead of sitting out her recv timeout, and Bob's root-cause exception
-    takes precedence over the secondary transport error Alice then sees.
-    """
-    return _drive_pair(alice, bob, alice.run, bob.serve)
-
-
-def run_pair_epoch(alice: AliceEndpoint, bob: BobEndpoint) -> dict[int, ReconcileResult]:
-    """Drive one staged continuous-sync epoch over a connected pair (both
-    sides must have called ``advance_epoch``); same threading and error
-    semantics as ``run_pair``."""
-    return _drive_pair(alice, bob, alice.run_epoch, bob.serve_epoch)

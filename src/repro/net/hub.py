@@ -8,6 +8,10 @@ a **channel id**, and exchanges ``repro.wire`` frames wrapped in the
 Peers run stock ``AliceEndpoint``s constructed with ``channel=``; their
 protocol, ledgers, and results are byte-identical to the pair path.
 
+The hub is the only serving (B-side) state machine.  The pair's serving
+side, ``BobEndpoint``, is a hub holding exactly one peer whose frames carry
+no envelope; ``run_pair`` drives it against one ``AliceEndpoint``.
+
 The point of the hub is *fusion*: all peers' sessions feed **one shared**
 ``SessionBatch(sides=("b",))``, so a global round packs every peer's active
 units into the same per-code cohorts — one ``encode_side`` (one
@@ -87,6 +91,8 @@ from .endpoint import (
     decode_side_b_round,
     encode_round_rows,
     encode_round_rows_ext,
+    reattach,
+    roll_back,
     round_schema,
     serve_epoch_frame,
     serve_phase0,
@@ -126,17 +132,20 @@ class PeerOutcome:
 class _Peer:
     """Hub-side connection state for one channel."""
 
-    def __init__(self, channel: int, transport: Transport, label: str | None):
+    def __init__(self, channel: int, transport: Transport, label: str | None,
+                 *, mux: bool = True):
         self.channel = channel
         self.label = label or f"peer{channel}"
         self.transport = transport
-        self.stream = FrameStream(transport, channel=channel)
+        # mux=False: the pair's one peer, whose frames carry no envelope
+        self.stream = FrameStream(transport, channel=channel if mux else None)
         self.pending: list[tuple] = []      # (set_b, cfg, d_known) pre-admission
         self.sessions: list[ReconSession] = []  # local-sid order
         self.admitted = False
         self.retired = False
         self.verified: list[bool] | None = None
         self.error: BaseException | None = None
+        self.cause: BaseException | None = None  # ``error``'s root failure
         self.tally = {
             "estimator": 0, "protocol": 0, "verify": 0, "epoch": 0,
             "resume": 0, "tree": 0,
@@ -167,7 +176,9 @@ class _Peer:
         self.suspend_at = 0.0           # monotonic expiry of the resume window
         self.suspend_err: BaseException | None = None
         self.resumes = 0
-        self.marks = {"protocol": 0, "verify": 0}   # tallies at last barrier
+        # tallies at the last barrier (a partial attempt's bytes above them
+        # move to the resume tally when the attempt re-runs)
+        self.marks = {"protocol": 0, "verify": 0, "epoch": 0, "estimator": 0}
         self.carry: dict = {}           # totals of resumed-away transports
         # per-peer registry: wire_stats routes through it so every key is
         # schema-declared and the dict is a derived snapshot (DESIGN.md §14)
@@ -261,12 +272,16 @@ class HubEndpoint:
     def add_peer(self, transport: Transport, *, label: str | None = None) -> int:
         """Register a peer connection; returns its channel id (never 0,
         never reused — a retired channel's id stays stale forever)."""
+        return self._attach(transport, label).channel
+
+    def _attach(self, transport: Transport, label: str | None, *,
+                mux: bool = True) -> _Peer:
         with self._lock:
             ch = self._next_channel
             self._next_channel += 1
-            self._peers[ch] = _Peer(ch, transport, label)
+            peer = self._peers[ch] = _Peer(ch, transport, label, mux=mux)
             self._joiners.append(ch)
-        return ch
+        return peer
 
     def submit(
         self,
@@ -332,6 +347,8 @@ class HubEndpoint:
         peer fails fast instead of hanging."""
         peer.retired = True
         peer.suspended = False
+        peer.verified = None
+        peer.cause = err
         if isinstance(err, TransportError):
             peer.error = err
         else:
@@ -393,6 +410,11 @@ class HubEndpoint:
         for peer in self._peers.values():
             if not peer.suspended or now < peer.suspend_at:
                 continue
+            if peer.verified is not None:
+                # the verify ack went out before the channel failed and the
+                # peer never came back for it: the exchange stands
+                self._retire_finished(peer)
+                continue
             cause = peer.suspend_err
             err = type(cause)(
                 f"{peer.label}: resume window ({self._resume_window}s) "
@@ -448,23 +470,9 @@ class HubEndpoint:
                 raise RuntimeError(
                     f"channel {channel} is not suspended (nothing to resume)"
                 )
-        old = peer.stream
-        t_old = old.transport
-        peer.carry = {
-            "transport_bytes_out": t_old.bytes_out
-            + peer.carry.get("transport_bytes_out", 0),
-            "transport_bytes_in": t_old.bytes_in
-            + peer.carry.get("transport_bytes_in", 0),
-            "retransmits": getattr(t_old, "retransmits", 0)
-            + peer.carry.get("retransmits", 0),
-        }
-        stream = FrameStream(transport, channel=channel)
-        stream.frames_out, stream.frames_in = old.frames_out, old.frames_in
-        stream.bytes_out, stream.bytes_in = old.bytes_out, old.bytes_in
-        stream.mux_bytes_out = old.mux_bytes_out
-        stream.mux_bytes_in = old.mux_bytes_in
+        peer.stream, peer.carry = reattach(peer.stream, transport, peer.carry)
         peer.transport = transport
-        peer.stream = stream
+        stream = peer.stream
         wait = self._deadline if timeout is None else timeout
         try:
             msg_type, payload = stream.recv(timeout=wait)
@@ -481,6 +489,15 @@ class HubEndpoint:
                     f"resume for channel {ch} epoch {epoch}, expected "
                     f"channel {channel} epoch {self._epoch - peer.epoch_base}"
                 )
+            if (
+                peer.epoch_pending is not None
+                and (a_rnd, a_digest)
+                == (0, wf.transcript_digest0(self._epoch - peer.epoch_base))
+                and self._epoch_served(peer)
+            ):
+                # she completed the epoch handshake we were answering when
+                # the channel failed: fold the epoch in as if it had closed
+                self._install_epoch(peer, 0)
             replay = False
             if a_rnd == peer.rounds_done:
                 if a_digest != peer.digest:
@@ -524,14 +541,9 @@ class HubEndpoint:
                         "replayed outcome frame rejected"
                     ) from peer.error
             else:
-                # the aborted partial attempt re-runs: its frame bytes move
-                # to the resume tally so Formula-(1) categories count the
-                # re-run exactly once (mirrors AliceEndpoint.resume)
-                for k, mark in peer.marks.items():
-                    spill = peer.tally[k] - mark
-                    if spill:
-                        peer.tally[k] = mark
-                        peer.tally["resume"] += spill
+                # the aborted partial attempt re-runs (mirrors
+                # AliceEndpoint.resume)
+                roll_back(peer.tally, peer.marks)
         except (TransportError, WireError) as e:
             if peer.error is None:      # replay rejection already evicted
                 self._evict(peer, e)
@@ -559,20 +571,29 @@ class HubEndpoint:
         """The final verification exchange (peer has no live work left)."""
         try:
             ack, flags = verify_ack_entries(payload, peer.sessions)
-            peer.tally["verify"] += framed_len(len(payload))
-            peer.stream.send(ack)
-            peer.tally["verify"] += len(ack)
         except WireError as e:
             self._evict(peer, e)
             return
+        peer.tally["verify"] += framed_len(len(payload)) + len(ack)
+        peer.verified = flags
+        try:
+            peer.stream.send(ack)
         except TransportError as e:
-            # ack send died: the exchange is re-runnable after a resume
-            # (the peer re-sends MSG_VERIFY; verify_ack_entries is pure)
+            # the ack may have arrived (only its transport ack was lost):
+            # a peer that missed it resumes and re-sends MSG_VERIFY
+            # (verify_ack_entries is pure); one that got it never returns,
+            # and its suspension lapses into a finished exchange
             self._fail(peer, e, resumable=True)
             return
+        self._retire_finished(peer)
+
+    def _retire_finished(self, peer: _Peer) -> None:
+        """Retire a peer whose verify exchange completed."""
         peer.marks = {k: peer.tally[k] for k in peer.marks}
-        peer.verified = flags
         peer.retired = True
+        peer.suspended = False
+        for sess in peer.sessions:
+            sess.suspended = False
         if not self._continuous:
             # a continuous-sync peer comes back next epoch; only one-shot
             # completion retires the channel id for good
@@ -592,7 +613,7 @@ class HubEndpoint:
         This one loop carries the straggler semantics of both the admission
         phase and the round barriers (DESIGN.md §10).
         """
-        resumable = phase == "round-barrier"
+        resumable = phase in ("round-barrier", "epoch-handshake")
         deadline_at = time.monotonic() + self._deadline
         pending = dict(handlers)
         while pending:
@@ -857,27 +878,35 @@ class HubEndpoint:
                     peer.d_known[i],
                 )
             peer.epoch_pending = pend
-            peer.epoch_plans = {}
             peer.retired = False
             peer.verified = None
         return self._epoch
 
-    def _epoch_handshake(self) -> None:
-        """The epoch-open barrier: every surviving peer owes its
+    def _open_epochs(self, rnd: int) -> None:
+        """Run the epoch handshake of every active peer with a staged
+        epoch: all peers when ``serve`` opens an epoch, later only peers
+        that resumed after failing mid-handshake (their sessions join at
+        global round ``rnd``)."""
+        peers = [
+            self._peers[ch] for ch in self._order
+            if not self._peers[ch].retired
+            and self._peers[ch].epoch_pending is not None
+        ]
+        if peers:
+            with self.tracer.span("hub.epoch_handshake", epoch=self._epoch,
+                                  round=rnd):
+                self._epoch_handshake(peers, rnd)
+
+    def _epoch_handshake(self, peers: list[_Peer], rnd: int) -> None:
+        """The epoch-open barrier: every peer in ``peers`` owes its
         ``MSG_EPOCH`` frames — one wrapped ToW sketch per estimator
         session (answered with a wrapped d̂ reply through the shared
         ``serve_phase0``), or a single bare epoch-open when the peer has
-        none — under the usual per-peer deadline; a silent peer is evicted
-        here exactly like at a round barrier.  Survivors' sessions then
+        none — under the usual per-peer deadline.  Survivors' sessions then
         fold the epoch in: fresh plans and round states, resident stores
-        delta-patched in place (zero rebuilds on the pure delta path).
+        delta-patched in place (zero rebuilds on the pure delta path).  A
+        peer suspended here keeps its staged epoch for ``resume_peer``.
         """
-        self._epoch_open = False
-        active = [
-            self._peers[ch] for ch in self._order
-            if not self._peers[ch].retired and self._peers[ch].epoch_pending
-        ]
-
         def _handler(ch):
             def handle(peer, msg_type, payload):
                 if msg_type != wf.MSG_EPOCH:
@@ -894,31 +923,45 @@ class HubEndpoint:
                 )
             return handle
 
+        for peer in peers:
+            peer.epoch_plans = {}
         self._poll_peers(
-            {p.channel: _handler(p.channel) for p in active},
+            {p.channel: _handler(p.channel) for p in peers},
             phase="epoch-handshake",
         )
-        for peer in active:
-            if peer.retired:                # evicted during the handshake
+        for peer in peers:
+            if not peer.retired:
+                self._install_epoch(peer, rnd)
+            elif not peer.suspended:        # evicted during the handshake
                 peer.epoch_pending = None
-                continue
-            pend, peer.epoch_pending = peer.epoch_pending, None
-            for i in sorted(pend):
-                set_b, dk = pend[i]
-                sess = peer.sessions[i]
-                plan = peer.epoch_plans.get(i) or plan_from_d_known(
-                    sess.plan.cfg, dk
-                )
-                advance_session(self._batch, sess, plan, new_b=set_b, rnd0=0)
-            peer.epoch_plans = {}
-            # re-arm the resumption record for the fresh epoch, mirroring
-            # the peer endpoint's _reset_rounds (rnd0 back to 0)
-            peer.rnd0 = 0
-            peer.rounds_done = 0
-            peer.digest = wf.transcript_digest0(self._epoch - peer.epoch_base)
-            peer.digest_prev = peer.digest
-            peer.inflight_ctx = None
-            peer.marks = {k: peer.tally[k] for k in peer.marks}
+
+    def _epoch_served(self, peer: _Peer) -> bool:
+        """Whether every estimator session of the peer's staged epoch has
+        its d̂ plan, i.e. the hub answered all of its epoch frames."""
+        return all(
+            i in peer.epoch_plans
+            for i, (_, dk) in peer.epoch_pending.items() if dk is None
+        )
+
+    def _install_epoch(self, peer: _Peer, rnd: int) -> None:
+        """Fold the peer's staged epoch into its sessions at global round
+        ``rnd`` and re-arm its resumption record, mirroring the peer
+        endpoint's ``_reset_rounds`` (her local round clock back to 0)."""
+        pend, peer.epoch_pending = peer.epoch_pending, None
+        for i in sorted(pend):
+            set_b, dk = pend[i]
+            sess = peer.sessions[i]
+            plan = peer.epoch_plans.get(i) or plan_from_d_known(
+                sess.plan.cfg, dk
+            )
+            advance_session(self._batch, sess, plan, new_b=set_b, rnd0=rnd)
+        peer.epoch_plans = {}
+        peer.rnd0 = rnd
+        peer.rounds_done = 0
+        peer.digest = wf.transcript_digest0(self._epoch - peer.epoch_base)
+        peer.digest_prev = peer.digest
+        peer.inflight_ctx = None
+        peer.marks = {k: peer.tally[k] for k in peer.marks}
 
     # -- the round barrier ------------------------------------------------
 
@@ -978,12 +1021,12 @@ class HubEndpoint:
         rnd = self._rnd = 0
         hook_fired_at = -1
         tracer = self.tracer
-        if self._epoch_open:
-            with tracer.span("hub.epoch_handshake", epoch=self._epoch):
-                self._epoch_handshake()
+        self._epoch_open = False
+        self._open_epochs(rnd)
         self._admit(rnd)
         while True:
             self._expire_overdue()
+            self._open_epochs(rnd)      # peers resumed mid-handshake
             active = [
                 self._peers[ch] for ch in self._order
                 if not self._peers[ch].retired
@@ -1204,16 +1247,18 @@ class HubEndpoint:
                     local, [results[g] for g in live_g],
                     round_schema(per, live_g),
                 )
+                # the reply may reach the peer even when the send raises
+                # (only its transport ack was lost), and she may then
+                # complete the round on her side: count it and retain the
+                # outcome context first, for an idempotent replay if her
+                # outcome frame never lands (DESIGN.md §13)
+                peer.tally["protocol"] += len(reply)
+                peer.inflight_ctx = (live_g, ctx)
                 try:
                     peer.stream.send(reply)
                 except TransportError as e:
                     self._fail(peer, e, resumable=True)
                     continue
-            peer.tally["protocol"] += len(reply)
-            # the reply is out: the peer may now complete the round on her
-            # side, so retain the outcome context for an idempotent replay
-            # if she crashes before her outcome frame lands (DESIGN.md §13)
-            peer.inflight_ctx = (live_g, ctx)
             round_ctx[ch] = (live_g, ctx)
         if round_ctx:
             self._rateless_phase(rnd, plans, per, sk_a_of, round_ctx)
@@ -1422,6 +1467,7 @@ class HubEndpoint:
                     [entries[sid] for sid in parts],
                     reply_schema[ch],
                 )
+                peer.tally["protocol"] += len(reply)    # as the main reply
                 try:
                     peer.stream.send(reply)
                 except TransportError as e:
@@ -1429,7 +1475,6 @@ class HubEndpoint:
                     fail.pop(ch, None)
                     round_ctx.pop(ch, None)
                     continue
-                peer.tally["protocol"] += len(reply)
                 if not fail.get(ch):
                     fail.pop(ch, None)
 
@@ -1547,3 +1592,141 @@ def run_hub_epoch(
     return _drive_hub(
         hub, {ch: ep.run_epoch for ch, ep in alices.items()}, join_timeout
     )
+
+
+class BobEndpoint:
+    """The serving endpoint of a pair: a ``HubEndpoint`` holding exactly one
+    peer, whose frames carry no ``MSG_MUX`` envelope (DESIGN.md §9).
+
+    Every round, rateless, outcome, epoch, ToW, tree and verify step is the
+    hub's.  The pair differs in what its caller sees: ``serve`` re-raises
+    the root cause of a failure — a malformed frame's ``WireError``, a
+    phase-0 ``EstimateOutOfRange``, a dead channel's ``TransportError`` —
+    which a hub records in the peer's ``PeerOutcome``.
+    """
+
+    def __init__(
+        self,
+        transport: Transport,
+        *,
+        interpret: bool | None = None,
+        continuous: bool = False,
+        degrade: bool = False,
+        estimate_limit: float | None = ESTIMATE_LIMIT_FRAC,
+        recorder: Recorder | None = None,
+        tracer=None,
+    ):
+        self._hub = HubEndpoint(
+            interpret=interpret, continuous=continuous, degrade=degrade,
+            estimate_limit=estimate_limit, recorder=recorder, tracer=tracer,
+        )
+        self._peer = self._hub._attach(transport, "pair", mux=False)
+        self.recorder = self._hub.recorder
+        self.tracer = self._hub.tracer
+
+    def submit(self, set_b, cfg: PBSConfig | None = None,
+               d_known: int | None = None) -> int:
+        """Enqueue this side of the next session (positional pairing with
+        the peer's ``submit`` order); returns its sid."""
+        return self._hub.submit(self._peer.channel, set_b, cfg, d_known)
+
+    def submit_tree(self, elems, cfg: PBSConfig | None = None,
+                    tree: TreeConfig | None = None) -> None:
+        """Stage this side of a tree-phase cold start (DESIGN.md §15); the
+        peer must ``submit_tree`` its side with the same ``cfg``/``tree``."""
+        self._hub.submit_tree(self._peer.channel, elems, cfg, tree)
+
+    def advance_epoch(self, mutations: dict | None = None, *,
+                      d_known: dict | None = None) -> int:
+        """Stage the next epoch (DESIGN.md §11): ``mutations`` maps sid ->
+        (added, removed) churn on B, ``d_known`` (sid -> int | None)
+        rebinds d conventions, as in ``HubEndpoint.advance_epoch``."""
+        if not self._peer.admitted:
+            raise RuntimeError("advance_epoch before the admission epoch ran")
+        ch = self._peer.channel
+        return self._hub.advance_epoch(
+            {ch: mutations or {}}, d_known={ch: d_known or {}}
+        )
+
+    def serve(self) -> None:
+        """Answer frames until the verification exchange completes."""
+        self._hub.serve()
+        if self._peer.cause is not None:
+            raise self._peer.cause
+
+    def serve_epoch(self) -> None:
+        """Serve one staged epoch: the peer's ``MSG_EPOCH`` handshake, the
+        in-place store delta patch, then frames until its verification."""
+        if not self._hub._epoch_open:
+            raise RuntimeError("no epoch staged: call advance_epoch first")
+        self.serve()
+
+    sessions = property(lambda self: self._peer.sessions)
+    verified = property(lambda self: self._peer.verified)
+    tree_depth = property(lambda self: self._peer.tree_depth)
+    tree_leaves = property(lambda self: self._peer.tree_leaves)
+    sessions_degraded = property(
+        lambda self: self._hub._stats.get("sessions_degraded", 0)
+    )
+    parity_extensions = property(
+        lambda self: self._hub._stats.get("parity_extensions", 0)
+    )
+
+    @property
+    def wire_stats(self) -> dict:
+        """Measured wire traffic of the pair's stream: a derived snapshot
+        of the ``wire.*`` metrics in ``recorder``, the same keys as
+        ``AliceEndpoint.wire_stats`` (DESIGN.md §14)."""
+        p = self._peer
+        self.recorder.publish(
+            "wire", stream_wire_stats(p.stream, p.tally, p.carry)
+        )
+        self.recorder.set("endpoint.resumes", p.resumes)
+        self.recorder.set("endpoint.sessions_degraded", self.sessions_degraded)
+        self.recorder.set("endpoint.parity_extensions", self.parity_extensions)
+        return self.recorder.view("wire")
+
+
+def _drive_pair(alice, bob, alice_call, bob_call) -> dict[int, ReconcileResult]:
+    """Run one Alice step against one Bob step on a worker thread, with
+    Bob's root-cause exception taking precedence (see ``run_pair``)."""
+    err: list[BaseException] = []
+
+    def _serve():
+        try:
+            bob_call()
+        except BaseException as e:  # noqa: BLE001 - relayed to the caller
+            err.append(e)
+            bob._peer.transport.close()  # unblock the peer's recv
+
+    th = threading.Thread(target=_serve, name="bob-endpoint", daemon=True)
+    th.start()
+    try:
+        results = alice_call()
+    except BaseException:
+        th.join(timeout=5.0)
+        if err:
+            raise err[0]  # Bob's failure is the root cause, not Alice's
+        raise
+    th.join(timeout=60.0)
+    if err:
+        raise err[0]
+    return results
+
+
+def run_pair(alice: AliceEndpoint, bob: BobEndpoint) -> dict[int, ReconcileResult]:
+    """Drive a connected endpoint pair to completion: Bob serves on a
+    worker thread, Alice runs on the caller's; Bob's exceptions re-raise.
+
+    A failing serve() closes Bob's transport so a blocked Alice fails fast
+    instead of sitting out her recv timeout, and Bob's root-cause exception
+    takes precedence over the secondary transport error Alice then sees.
+    """
+    return _drive_pair(alice, bob, alice.run, bob.serve)
+
+
+def run_pair_epoch(alice: AliceEndpoint, bob: BobEndpoint) -> dict[int, ReconcileResult]:
+    """Drive one staged continuous-sync epoch over a connected pair (both
+    sides must have called ``advance_epoch``); same threading and error
+    semantics as ``run_pair``."""
+    return _drive_pair(alice, bob, alice.run_epoch, bob.serve_epoch)

@@ -287,24 +287,64 @@ def test_tree_rateless_leaf_recovery():
 
 
 def test_bob_rejects_out_of_band_parity_frames():
+    """A MSG_PARITY frame the serving side does not expect fails the pair's
+    serve loop with a clean WireError: before any round, in a round with
+    no extension pending, and one level beyond MAX_PARITY_EXTENSIONS."""
+    from repro.net import FrameStream
     from repro.wire import frames as wf
 
-    _, tb = InMemoryDuplex.pair()
+    refused = f"got 0x{wf.MSG_PARITY:02x}"
+    a, b = make_pair(2000, 20, np.random.default_rng(12))
+
+    # no round in flight at all: a parity frame where round 1's sketches
+    # belong
+    ta, tb = InMemoryDuplex.pair()
     bob = BobEndpoint(tb)
-    # no round in flight at all
-    with pytest.raises(WireError, match="no round in flight"):
-        bob._handle_parity(b"\x01\x01")
-    # round in flight but nothing failing: no extension is pending
-    bob._ctx = {
-        "live": [], "ctx": {}, "per": {}, "plans": [], "sk_a": {},
-        "fail": {}, "level": 0, "acc": {},
-    }
-    with pytest.raises(WireError, match="no extension pending"):
-        bob._handle_parity(b"\x01\x01")
-    # ladder exhausted: one frame past the cap is a protocol violation
-    bob._ctx = {"fail": {0: [0]}, "level": MAX_PARITY_EXTENSIONS}
-    with pytest.raises(WireError, match="cap"):
-        bob._handle_parity(b"\x01" + bytes([MAX_PARITY_EXTENSIONS + 1]))
+    bob.submit(b, cfg=PBSConfig(seed=2), d_known=20)
+    FrameStream(ta).send(wf.encode_parity(1, 1, []))
+    with pytest.raises(WireError, match=refused):
+        bob.serve()
+
+    # round in flight but no extension pending: a parity frame where the
+    # outcome belongs, from a session that is not rateless
+    class _ParityAfterReply(AliceEndpoint):
+        def _rateless_ladder(self, rnd, plans, per, live, ent_of):
+            self._stream.send(wf.encode_parity(rnd, 1, []))
+            self._expect(wf.MSG_ROUND_REPLY)    # Bob dies first
+            raise AssertionError("unreachable")
+
+    ta, tb = InMemoryDuplex.pair()
+    alice, bob = _ParityAfterReply(ta), BobEndpoint(tb)
+    alice.submit(a, cfg=PBSConfig(seed=2), d_known=20)
+    bob.submit(b, cfg=PBSConfig(seed=2), d_known=20)
+    with pytest.raises(WireError, match=refused):
+        run_pair(alice, bob)
+
+    # ladder exhausted: a unit still failing after all MAX levels (t = 2
+    # -> 32, under the (255 - 1) // 2 code cap), then one frame past them
+    class _PastTheCap(AliceEndpoint):
+        def _rateless_ladder(self, rnd, plans, per, live, ent_of):
+            before = self.parity_extensions
+            out = super()._rateless_ladder(rnd, plans, per, live, ent_of)
+            if all(ok for sid in live for ok in ent_of[sid][0]):
+                return out
+            assert self.parity_extensions - before == MAX_PARITY_EXTENSIONS
+            self._stream.send(
+                wf.encode_parity(rnd, MAX_PARITY_EXTENSIONS + 1, [])
+            )
+            self._expect(wf.MSG_ROUND_REPLY)    # Bob dies first
+            raise AssertionError("unreachable")
+
+    cfg = PBSConfig(seed=6, n_override=255, t_override=2, g_override=1,
+                    rateless=True)
+    a, b = make_pair(2000, 400, np.random.default_rng(17))
+    ta, tb = InMemoryDuplex.pair()
+    alice, bob = _PastTheCap(ta), BobEndpoint(tb)
+    alice.submit(a, cfg=cfg, d_known=400)
+    bob.submit(b, cfg=cfg, d_known=400)
+    with pytest.raises(WireError, match=refused):
+        run_pair(alice, bob)
+    assert alice.parity_extensions == bob.parity_extensions > 0
 
 
 def test_bob_rejects_stale_round_parity():

@@ -398,6 +398,14 @@ def test_silent_crash_suspends_at_deadline_then_resumes():
     cfg = PBSConfig(seed=4)
     d = len(np.setxor1d(a, b))
 
+    # a warm pair at the same shapes compiles every round's executors
+    # first, so the 1 s barrier deadline below times the silent crash alone
+    t_a_raw, t_h = InMemoryDuplex.pair()
+    warm_a, warm_b = AliceEndpoint(t_a_raw), BobEndpoint(t_h)
+    warm_a.submit(a, cfg=cfg, d_known=d)
+    warm_b.submit(b, cfg=cfg, d_known=d)
+    assert run_pair(warm_a, warm_b)[0].success
+
     t_a_raw, t_h = InMemoryDuplex.pair()
     t_a = ChaosTransport(
         t_a_raw, FaultPlan(crash_after_sends=2, crash_silent=True)
@@ -437,6 +445,95 @@ def test_silent_crash_suspends_at_deadline_then_resumes():
     assert kinds == ["deadline"]         # caught by PeerDeadline, not close
     assert outcomes[ch].ok and outcomes[ch].error_kind == "resumed"
     assert hub.stats["peers_resumed"] == 1
+
+
+class _LostAck(Transport):
+    """The hub's side of one peer: once ``armed``, the first send of an
+    inner frame of type ``kind`` fails after the frame was delivered (only
+    its transport ack was lost) or before (``delivered=False``)."""
+
+    def __init__(self, inner: Transport, kind: int, delivered: bool):
+        super().__init__()
+        self.inner, self.kind, self.delivered = inner, kind, delivered
+        self.armed = self.fired = False
+
+    def send(self, data: bytes) -> None:
+        _, payload, _ = wf.split_frame(bytearray(data), 0)
+        _, inner_type, _ = wf.decode_mux(payload)
+        if self.armed and not self.fired and inner_type == self.kind:
+            self.fired = True
+            if self.delivered:
+                self.inner.send(data)
+            self.inner.close()
+            raise TransportError("lost ack" if self.delivered else "lost")
+        self.inner.send(data)
+
+    def recv(self, timeout: float | None = None) -> bytes:
+        return self.inner.recv(timeout)
+
+    def close(self) -> None:
+        self.inner.close()
+
+
+@pytest.mark.parametrize("delivered", [True, False], ids=["arrived", "lost"])
+@pytest.mark.parametrize(
+    "kind,dk",
+    [(wf.MSG_EPOCH, None), (wf.MSG_ROUND_REPLY, 40), (wf.MSG_VERIFY_ACK, 40)],
+    ids=["epoch_reply", "round_reply", "verify_ack"],
+)
+def test_send_failure_resumes_whether_or_not_it_arrived(kind, dk, delivered):
+    """A hub send that raises may still have reached the peer. Whichever
+    it was — an epoch reply, a round reply or a verify ack — the epoch
+    completes byte-identical to the oracle: the peer resumes from where it
+    stands, or (an ack it got) never needs to."""
+    a, b = make_pair(1500, 40, np.random.default_rng(5))
+    cfg = PBSConfig(seed=3, **_CFG)
+    hub = HubEndpoint(continuous=True, resume_window=5.0, recv_deadline=10.0)
+    ta, th = InMemoryDuplex.pair()
+    lossy = _LostAck(th, kind, delivered)
+    ch = hub.add_peer(lossy)
+    hub.submit(ch, b, cfg=cfg, d_known=dk)
+    ep = AliceEndpoint(ta, channel=ch, continuous=True)
+    ep.submit(a, cfg=cfg, d_known=dk)
+    pending: dict = {}
+
+    def on_barrier(rnd):
+        if "t" in pending and hub._peers[ch].suspended:
+            hub.resume_peer(ch, pending.pop("t"))
+
+    hub.on_barrier = on_barrier
+
+    def resuming(fn):
+        def call():
+            try:
+                return fn()
+            except TransportError:
+                pass
+            na, pending["t"] = InMemoryDuplex.pair()
+            ep.resume(na)
+            return ep.resume_run()
+        return call
+
+    outcomes, _, errors = _drive_hub(hub, {ch: resuming(ep.run)}, 60.0)
+    assert not errors and outcomes[ch].ok
+    rng = np.random.default_rng(1)
+    hub.advance_epoch({ch: {0: (_fresh_elems(rng, 20), b[:10])}})
+    ep.advance_epoch({0: (_fresh_elems(rng, 5), ep.sessions[0].state.a[:5])})
+    lossy.armed = True
+    outcomes, results, errors = _drive_hub(
+        hub, {ch: resuming(ep.run_epoch)}, 60.0
+    )
+    assert lossy.fired
+    assert not errors, errors
+    assert outcomes[ch].ok and outcomes[ch].verified == [True]
+    a_e, b_e = ep.sessions[0].state.a, hub._peers[ch].sessions[0].state.b
+    oracle = reconcile(a_e, b_e, cfg, d_known=dk)
+    res = results[ch][0]
+    assert res.diff == oracle.diff == true_diff(a_e, b_e)
+    assert res.bytes_per_round == oracle.bytes_per_round
+    # an ack the peer got needs no resume; anything else takes one
+    assert ep.resumes == int(not (delivered and kind == wf.MSG_VERIFY_ACK))
+    assert hub.stats.get("peers_failed", 0) == 0
 
 
 def test_resume_rejected_on_diverged_transcript():
