@@ -252,17 +252,31 @@ class SessionState:
         return [u for u in self.units if not u.done]
 
 
+def _group_key_dtype(g: int) -> np.dtype:
+    """Narrowest dtype that holds every group id below ``g``: numpy's stable
+    sort of ≤ 16-bit integers is a radix sort, O(N) where int64 ids pay a
+    comparison merge sort."""
+    if g <= 1 << 8:
+        return np.dtype(np.uint8)
+    if g <= 1 << 16:
+        return np.dtype(np.uint16)
+    return np.dtype(np.int64)
+
+
 def group_view(elems: np.ndarray, g: int, seed_groups: int):
-    """Group ids + stable order + group boundaries for one element array."""
+    """Group ids (int64) + stable order + group boundaries for one element
+    array; within a group the order keeps the input order."""
     grp = hash_to_range(elems, g, seed_groups)
-    order = np.argsort(grp, kind="stable")
-    bounds = np.searchsorted(grp[order], np.arange(g + 1))
+    order = np.argsort(grp.astype(_group_key_dtype(g), copy=False), kind="stable")
+    bounds = np.zeros(g + 1, dtype=np.int64)
+    np.cumsum(np.bincount(grp, minlength=g), out=bounds[1:])
     return grp, order, bounds
 
 
 def new_session_state(a: np.ndarray, b: np.ndarray, plan: ProtocolPlan) -> SessionState:
     tracer = current_tracer()
-    with tracer.span("session.group_view", keys=len(a) + len(b)):
+    with tracer.span("session.group_view", keys=len(a) + len(b),
+                     key_bits=_group_key_dtype(plan.g).itemsize * 8):
         grp_b, order_b, bounds_b = group_view(b, plan.g, plan.seed_groups)
         grp_a, order_a, bounds_a = group_view(a, plan.g, plan.seed_groups)
     with tracer.span("session.member_set", keys=len(a)) as span:

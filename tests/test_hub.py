@@ -15,7 +15,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.core.pbs import PBSConfig, reconcile, true_diff
+from repro.core.pbs import PBSConfig, plan_from_d_known, reconcile, true_diff
 from repro.core.simdata import make_pair, make_pair_two_sided
 from repro.net import (
     AliceEndpoint,
@@ -388,6 +388,11 @@ def test_hub_trace_spans_cover_and_nest():
     inside("decode.reply_units", "hub.decode")
     inside("session.group_view", "session.state")
     inside("session.member_set", "session.state")
+    # the set-up names the id width its stable sort ran over: a radix sort
+    # of uint8 ids up to 256 groups, of uint16 up to 65,536
+    gs = {plan_from_d_known(PBSConfig(seed=70 + i), 12).g for i in range(3)}
+    want = {8 if g <= 256 else 16 if g <= 65_536 else 64 for g in gs}
+    assert {s["args"]["key_bits"] for s in by["session.group_view"]} == want
     # every A on the served path arrives sorted: membership reuses it, no copy
     assert all(s["args"]["sorted"] is True for s in by["session.member_set"])
     # each session state is built inside a peer's submit or the admission
